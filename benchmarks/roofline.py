@@ -3,7 +3,9 @@
 Terms (per assignment):
     compute    = HLO_FLOPs / (chips * 197 TF/s)
     memory     = HLO_bytes / (chips * 819 GB/s)
-    collective = collective_bytes / (chips * 50 GB/s)
+    collective = collective_bytes / (chips * 200 GB/s)   (1,600 Gbit/s)
+
+Peaks come from ``repro.hw.TARGET`` (TPU v5e).
 
 HLO_FLOPs / bytes come from the trip-count-attributed HLO analyzer (per
 device; equivalent to global/chips).  MODEL_FLOPS = 6*N_active*tokens
@@ -16,7 +18,7 @@ import argparse
 import json
 
 from repro.configs import SHAPES, get_config
-from repro.launch.constants import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16)
+from repro.hw import TARGET
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -35,22 +37,22 @@ def roofline_row(rec: dict) -> dict | None:
         return None
     chips = rec["devices"]
     hc = rec["hlo_cost"]
-    compute = hc["flops"] / PEAK_FLOPS_BF16                  # per-device flops
+    compute = hc["flops"] / TARGET.bf16_flops            # per-device flops
     # memory term uses the fusion-optimistic byte model (see hlo_analysis);
     # hbm_bytes (zero-fusion upper bound) is reported alongside.
-    memory = hc.get("hbm_fused", hc["hbm_bytes"]) / HBM_BW
-    collective = hc["total_collective_bytes"] / ICI_BW
+    memory = hc.get("hbm_fused", hc["hbm_bytes"]) / TARGET.hbm_bw
+    collective = hc["total_collective_bytes"] / TARGET.ici_bw
     terms = {"compute": compute, "memory": memory, "collective": collective}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"])
-    useful_time = mf / (chips * PEAK_FLOPS_BF16)
+    useful_time = mf / (chips * TARGET.bf16_flops)
     step_time = max(terms.values())
     hbm_gb = (rec["memory"]["argument_bytes"] + rec["memory"]["temp_bytes"]) / 1e9
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "chips": chips,
         "compute_s": compute, "memory_s": memory, "collective_s": collective,
-        "memory_raw_s": hc["hbm_bytes"] / HBM_BW,
+        "memory_raw_s": hc["hbm_bytes"] / TARGET.hbm_bw,
         "dominant": dominant,
         "model_flops": mf,
         "hlo_flops_global": hc["flops"] * chips,

@@ -43,6 +43,9 @@ def main() -> None:
     ap.add_argument("--dryrun-jsonl", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.section in ("all", "conditions"):
         print("== paper §5.1.2 conditions (loop extraction & narrowing) ==")
         from benchmarks import loop_extraction

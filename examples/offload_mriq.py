@@ -38,12 +38,12 @@ report = AutoOffloader(
     program, cache=PlanCache.default())
 print(report.summary())
 
-print("\n--- deploy kernel validation (Pallas, interpret mode) ---")
+print("\n--- deploy kernel validation (Pallas; interpreted off a TPU) ---")
 ks = jax.random.split(jax.random.PRNGKey(0), 7)
 x, y, z = (jax.random.normal(ks[i], (512,)) for i in range(3))
 kx, ky, kz = (jax.random.normal(ks[3 + i], (256,)) * 0.1 for i in range(3))
 pm = jax.random.uniform(ks[6], (256,))
-qr, qi = mriq_compute_q(x, y, z, kx, ky, kz, pm, interpret=True)
+qr, qi = mriq_compute_q(x, y, z, kx, ky, kz, pm)
 qr_ref, qi_ref = mriq_ref(x, y, z, kx, ky, kz, pm)
 err = float(max(np.abs(np.asarray(qr - qr_ref)).max(),
                 np.abs(np.asarray(qi - qi_ref)).max()))

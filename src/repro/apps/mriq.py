@@ -63,7 +63,7 @@ def _q_offload(x, y, z, kx, ky, kz, pm):
 
 @register_variant("compute_q", "pallas")
 def _q_pallas(x, y, z, kx, ky, kz, pm):
-    return mriq_compute_q(x, y, z, kx, ky, kz, pm, interpret=True)
+    return mriq_compute_q(x, y, z, kx, ky, kz, pm)
 
 
 # ---------------------------------------------------------------------------

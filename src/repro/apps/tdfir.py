@@ -21,7 +21,7 @@ from repro.core.program import OffloadableProgram, Region
 from repro.core.regions import (Impl, TuningSpace, dispatch,
                                 register_variant)
 from repro.core.resources import VMEM_BUDGET
-from repro.kernels.fir import fir_filter_bank
+from repro.kernels.fir import fir_filter_bank, fir_vmem_bytes
 from repro.kernels.ref import fir_ref
 
 
@@ -71,19 +71,17 @@ def _fir_offload(x, h):
 
 def _fir_tile_ok(p, args) -> bool:
     """fir_bank tile legality: block_n divides the sample count, tap_unroll
-    divides the tap count, and the per-grid-step VMEM footprint (halo'd x
-    tile + taps + output tile, 2 float32 planes each) fits the budget.
-    Unbound queries (no args) accept every point."""
+    divides the tap count, and the per-grid-step VMEM footprint fits the
+    budget.  Unbound queries (no args) accept every point."""
     if not args:
         return True
     try:
-        n, k = args[0].shape[1], args[1].shape[1]
+        m, n, k = args[0].shape[0], args[0].shape[1], args[1].shape[1]
     except (IndexError, AttributeError):
         return True
     bn, tu = p["block_n"], p["tap_unroll"]
-    vmem = 8.0 * ((bn + k - 1) + k + bn)
     return (bn <= n and n % bn == 0 and tu <= k and k % tu == 0
-            and vmem <= VMEM_BUDGET)
+            and fir_vmem_bytes(m, k, bn) <= VMEM_BUDGET)
 
 
 @register_variant("fir_bank", "pallas", tuning=TuningSpace(
@@ -91,8 +89,7 @@ def _fir_tile_ok(p, args) -> bool:
     defaults={"block_n": 512, "tap_unroll": 1},
     validity=_fir_tile_ok))
 def _fir_pallas(x, h, *, block_n=512, tap_unroll=1):
-    return fir_filter_bank(x, h, block_n=block_n, tap_unroll=tap_unroll,
-                           interpret=True)
+    return fir_filter_bank(x, h, block_n=block_n, tap_unroll=tap_unroll)
 
 
 # ---------------------------------------------------------------------------

@@ -64,15 +64,13 @@ from dataclasses import dataclass, field
 
 from repro.core.intensity import TRANSCENDENTAL_WEIGHT
 from repro.core.regions import canonical_gene, gene_variant, tuning_space
+from repro.hw import TARGET, TRANSCENDENTAL_RATE
 
-# Accelerator-side seeds (TPU v5e class) — numerically the same figures as
-# repro/launch/constants.py, restated here rather than imported: core must
-# not depend on launch (launch imports core throughout, and a future
-# core-import in that module would close a circular import), and only the
+# Accelerator-side seeds: the target chip's published peaks.  Only the
 # host-vs-accelerator ratio matters before calibration replaces the scale.
-ACCEL_FLOPS = 197e12            # peak bf16 flop/s per chip
-ACCEL_BW = 819e9                # HBM bytes/s per chip
-ACCEL_TRANSCENDENTAL_RATE = 1e12  # VPU transcendental retire rate, elem/s
+ACCEL_FLOPS = TARGET.bf16_flops           # peak bf16 flop/s per chip
+ACCEL_BW = TARGET.hbm_bw                  # HBM bytes/s per chip
+ACCEL_TRANSCENDENTAL_RATE = TRANSCENDENTAL_RATE   # elem/s, an estimate
 
 # Host-side seeds (sequential loop-faithful ref code).  Only the
 # host-vs-accelerator *ratio* matters before calibration kicks in.

@@ -20,7 +20,7 @@ Layers
 ------
 enumerator
     :func:`enumerate_sites` / ``_Ctx``: trace the function, walk the jaxpr
-    descending ``scan``/``while``/``cond``/``pjit`` sub-jaxprs, and emit
+    descending ``scan``/``while``/``cond``/``jit`` sub-jaxprs, and emit
     candidate sites — the TPU analogue of the paper's loop statements:
     scans (affine carries, softmax-normalized matmul chains, FIR shapes),
     ``rsqrt`` norm anchors, gated ``dot_general`` clusters.
@@ -73,7 +73,7 @@ _FLOAT_OK = ("bfloat16", "float32")
 _FIR_OK = ("complex64", "float32")
 
 # higher-order primitives whose single sub-jaxpr is evaluated inline
-_WRAPPERS = ("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
+_WRAPPERS = ("jit", "closed_call", "custom_jvp_call", "custom_vjp_call",
              "remat2", "checkpoint", "custom_vjp_call_jaxpr")
 
 # pure data-layout primitives (peelable during operand recovery)
@@ -210,7 +210,7 @@ def enumerate_sites(ctx: _Ctx) -> list[CandidateSite]:
                 sites.append(CandidateSite("conv", node.path, i, name))
             elif name == "top_k":
                 sites.append(CandidateSite("route", node.path, i, name))
-            elif name == "pjit" and _silu_inner(e) is not None:
+            elif name == "jit" and _silu_inner(e) is not None:
                 sites.append(CandidateSite("gate", node.path, i, name))
     return sites
 
@@ -221,7 +221,7 @@ def enumerate_sites(ctx: _Ctx) -> list[CandidateSite]:
 def _peel(ctx: _Ctx, jaxpr, v, allowed):
     """Follow ``v`` back through producer eqns whose primitive is in
     ``allowed``, staying at (or returning to) the given jaxpr level.
-    Wrapper eqns (pjit around a pad, sharding constraints) are crossed only
+    Wrapper eqns (jit around a pad, sharding constraints) are crossed only
     when the chain fully exits through one of their inputs.  ``mul``/
     ``div``/``add`` are followed through their non-scalar operand."""
     while True:
@@ -741,8 +741,8 @@ def _match_affine_while(ctx: _Ctx, jid: int, idx: int) -> Optional[RegionMatch]:
 # Recognizer: SwiGLU MLP (gated dot_general cluster)
 # ---------------------------------------------------------------------------
 def _silu_inner(eqn):
-    """Is this pjit a traced ``silu`` (logistic + self-mul)?  -> inner jaxpr"""
-    if eqn.primitive.name != "pjit":
+    """Is this jit a traced ``silu`` (logistic + self-mul)?  -> inner jaxpr"""
+    if eqn.primitive.name != "jit":
         return None
     inner = eqn.params.get("jaxpr")
     if inner is None or len(eqn.invars) != 1 or len(eqn.outvars) != 1:
@@ -1374,7 +1374,7 @@ def _find_matches(ctx: _Ctx):
         ("while", _match_affine_while),
         ("top_k", _match_moe_dispatch),
         ("conv_general_dilated", _match_conv_stem),
-        ("pjit", _match_swiglu),
+        ("jit", _match_swiglu),
         ("tanh", _match_gelu_mlp),
         ("rsqrt", _match_rmsnorm),
     )

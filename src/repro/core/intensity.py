@@ -142,7 +142,7 @@ def _count_jaxpr(jaxpr, mult: float, acc: RegionAnalysis) -> None:
             for branch in eqn.params["branches"]:
                 _count_jaxpr(branch.jaxpr, mult, acc)
             continue
-        elif prim in ("pjit", "custom_jvp_call", "custom_vjp_call",
+        elif prim in ("jit", "custom_jvp_call", "custom_vjp_call",
                       "custom_vjp_call_jaxpr", "closed_call", "remat", "checkpoint"):
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr")
             if inner is not None:

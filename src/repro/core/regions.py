@@ -9,8 +9,8 @@ one or more *variants*:
 * ``offload`` — the restructured high-performance implementation (vectorized /
                 fused — what the Pallas kernel computes), timeable on any
                 backend,
-* ``pallas``  — the Pallas TPU kernel itself (validated with interpret=True
-                on CPU; the deploy target on real hardware).
+* ``pallas``  — the Pallas TPU kernel itself (compiled by Mosaic on a TPU,
+                interpreted on any other backend).
 
 An *offload pattern* (paper §3.3) is a mapping ``{region -> gene}``; the
 planner searches over patterns.  A gene is either a bare variant name

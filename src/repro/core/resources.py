@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.fir import fir_vmem_bytes
+
 VMEM_BUDGET = 16 * 1024 * 1024      # 16 MiB per TPU core
 
 # (region, variant) -> fn(*abstract_args) -> vmem bytes.  Mirrors each
@@ -125,9 +127,7 @@ def precompile_many(jobs, mapper=map) -> list[ResourceEstimate]:
 # ---------------------------------------------------------------------------
 @register_vmem_estimator("fir_bank", "pallas")
 def _fir_vmem(x, h, *_):
-    k = h.shape[-1]
-    block_n = 512
-    return 4.0 * (2 * (block_n + k - 1) + 2 * k + 2 * block_n)
+    return fir_vmem_bytes(x.shape[0], h.shape[-1], min(512, x.shape[-1]))
 
 
 @register_vmem_estimator("compute_q", "pallas")
@@ -146,11 +146,12 @@ def _flash_vmem(q, k, v, *_):
 @register_vmem_estimator("rglru_scan", "pallas")
 def _rglru_vmem(a, b, h0, *_):
     bc, tc = 128, 128
-    return 4.0 * (2 * tc * bc + 2 * bc + tc * bc)
+    return 4.0 * (3 * tc * bc + bc)            # a, b, y tiles + state
 
 
 @register_vmem_estimator("ssm_scan", "pallas")
 def _ssm_vmem(a, bx, c, h0, *_):
+    # state-major tiles [tc, N, bc]; the C column [tc, N, 1] is lane-padded
     n = a.shape[-1]
     bc, tc = 128, 64
-    return 4.0 * (2 * tc * bc * n + bc * n + tc * n + tc * bc)
+    return 4.0 * (2 * tc * n * bc + tc * n * 128 + tc * bc + n * bc)

@@ -37,6 +37,7 @@ speculative compile-ahead.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -108,10 +109,15 @@ def aot_lower(fn, args) -> tuple:
     Python tracing — GIL-bound — so a concurrent executor runs it on the
     driver thread and ships only :func:`finish_compile` (the GIL-releasing
     XLA compile) to its worker pool.  Returns ``(lowered | None, seconds,
-    error)`` and never raises."""
+    error)`` and never raises.
+
+    ``fn`` is traced through a fresh wrapper each call: JAX's trace cache
+    makes a second trace of the same function wait for one in flight, so a
+    retry would otherwise block behind a trace the watchdog abandoned."""
     t0 = time.perf_counter()
     try:
-        return jax.jit(fn).lower(*args), time.perf_counter() - t0, ""
+        lowered = jax.jit(functools.partial(fn)).lower(*args)
+        return lowered, time.perf_counter() - t0, ""
     except Exception as e:  # noqa: BLE001 — a pattern failing = not a solution
         return None, time.perf_counter() - t0, f"{type(e).__name__}: {e}"
 

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, sp_ref, pos_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *,
                      window: int = 0, block_k: int = 512,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: [B, Hq, 1, D]; k/v_cache: [B, Hkv, S, D]; slot_pos: [B, S] int32;
     cur_pos: [B] int32.  Returns [B, Hq, 1, D]."""
     b, hq, _, d = q.shape
@@ -105,6 +107,6 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *,
             pltpu.VMEM((g,), jnp.float32),      # l (normalizer)
             pltpu.VMEM((g, d), jnp.float32),    # acc
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, kf, vf, spf, posf)
     return out.reshape(b, hq, 1, d)

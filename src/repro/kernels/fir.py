@@ -1,13 +1,17 @@
 """tdFIR Pallas kernel — the paper's first evaluation app (HPEC challenge).
 
 Complex FIR filter bank: for bank m, output sample n:
-    y[m, n] = sum_k h[m, k] * x[m, n + K - 1 - k]      (complex MAC)
+    y[m, n] = sum_k h[m, k] * x[m, n - k]      (complex MAC, x[<0] = 0)
 
-where x is pre-padded with K-1 leading zeros (causal).  TPU adaptation of the
-paper's FPGA offload: one grid step per (bank, output tile); the padded input
-row tile (+K-1 halo) and the K taps live in VMEM; the tap loop runs on the
-VPU over 128-lane output vectors.  The paper's loop-unroll knob ``b`` maps to
-``tap_unroll`` (taps processed per fori_loop step).
+TPU adaptation of the paper's FPGA offload.  The kernel works on the
+transposed layout — samples on sublanes, banks on lanes — so every block
+meets the (8, 128) tiling: banks are blocked by 128 lanes (or all of them
+when there are fewer), samples by ``block_n`` rows.  Each grid step reads a
+window of ``block_n + halo`` input rows (``halo`` = K-1 rounded up to 8),
+overlapping its predecessor by the halo; tap k then multiplies the window
+rows ``[halo - k, halo - k + block_n)`` by the bank-wise tap row ``h[k]``
+on the VPU.  The paper's loop-unroll knob ``b`` maps to ``tap_unroll``
+(taps processed per fori_loop step).
 
 Complex numbers are carried as separate re/im planes (TPU has no complex
 vector unit; 4 real MACs per complex MAC, 8 flops — same count the paper's
@@ -22,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def largest_divisor(n: int, cap: int) -> int:
     """The largest divisor of ``n`` that is <= ``cap`` (>= 1).  Used to
@@ -35,26 +41,42 @@ def largest_divisor(n: int, cap: int) -> int:
     return 1
 
 
+def fir_halo(n_taps: int) -> int:
+    """Input rows a sample block reads before its first sample: K-1,
+    rounded up to the 8-row sublane tile."""
+    return -(-(n_taps - 1) // 8) * 8
+
+
+def fir_bank_block(n_banks: int) -> int:
+    """Banks per grid step: one 128-lane tile, or every bank when the
+    count is not a multiple of 128 (a block equal to the array is legal)."""
+    return 128 if n_banks % 128 == 0 else n_banks
+
+
+def fir_vmem_bytes(n_banks: int, n_taps: int, block_n: int) -> float:
+    """VMEM one grid step holds: the input window, the taps and the output
+    tile, re and im planes each, float32, one lane-padded bank block wide."""
+    lanes = -(-fir_bank_block(n_banks) // 128) * 128
+    rows = 2 * (block_n + fir_halo(n_taps)) + 2 * n_taps + 2 * block_n
+    return 4.0 * rows * lanes
+
+
 def _fir_kernel(xr_ref, xi_ref, hr_ref, hi_ref, yr_ref, yi_ref, *,
-                n_taps: int, block_n: int, tap_unroll: int,
-                whole_row: bool = False):
-    # x block: [1, block_n + n_taps - 1] (halo) — or, when this Pallas build
-    # has no Element indexing for overlapping blocks, the whole padded row
-    # (whole_row=True) with the tile offset recovered from the grid position.
-    base = pl.program_id(1) * block_n if whole_row else 0
-    acc_r = jnp.zeros((1, block_n), jnp.float32)
-    acc_i = jnp.zeros((1, block_n), jnp.float32)
+                n_taps: int, halo: int, block_n: int, tap_unroll: int):
+    # x window: [block_n + halo, banks]; window row halo + n is sample n of
+    # this block, so tap k reads rows [halo - k, halo - k + block_n)
+    banks = yr_ref.shape[-1]
+    acc_r = jnp.zeros((block_n, banks), jnp.float32)
+    acc_i = jnp.zeros((block_n, banks), jnp.float32)
 
     def tap_body(t, carry):
         ar, ai = carry
         for u in range(tap_unroll):                       # paper's unroll `b`
             k = t * tap_unroll + u
-            hr = hr_ref[0, k]
-            hi = hi_ref[0, k]
-            # x window aligned so tap k multiplies x[n + K - 1 - k]
-            off = base + n_taps - 1 - k
-            xr = pl.load(xr_ref, (pl.ds(0, 1), pl.ds(off, block_n)))[0]
-            xi = pl.load(xi_ref, (pl.ds(0, 1), pl.ds(off, block_n)))[0]
+            hr = hr_ref[pl.ds(k, 1), :]                   # [1, banks]
+            hi = hi_ref[pl.ds(k, 1), :]
+            xr = xr_ref[pl.ds(halo - k, block_n), :]      # [block_n, banks]
+            xi = xi_ref[pl.ds(halo - k, block_n), :]
             ar = ar + hr * xr - hi * xi
             ai = ai + hr * xi + hi * xr
         return ar, ai
@@ -67,11 +89,13 @@ def _fir_kernel(xr_ref, xi_ref, hr_ref, hi_ref, yr_ref, yi_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("block_n", "tap_unroll", "interpret"))
 def fir_filter_bank(x: jax.Array, h: jax.Array, *, block_n: int = 512,
-                    tap_unroll: int = 1, interpret: bool = True) -> jax.Array:
+                    tap_unroll: int = 1,
+                    interpret: bool | None = None) -> jax.Array:
     """x: complex64 [M, N]; h: complex64 [M, K].  Returns y [M, N].
 
-    VMEM per grid step: (block_n + K-1 + K + block_n) * 2 planes * 4B
-    ~= (512+127+128+512)*8B = 10 KB << 16 MiB; block_n is lane-aligned."""
+    VMEM per grid step at HPEC set 1 (M=64 banks padded to 128 lanes,
+    K=128, block_n=512): (2 * (512 + 128) + 2 * 128 + 2 * 512) rows *
+    128 lanes * 4 B ~= 1.3 MB, double-buffered ~= 2.6 MB << 16 MiB."""
     m, n = x.shape
     _, k = h.shape
     # proposed tile knobs are clamped, not asserted: the tuner owns
@@ -90,48 +114,32 @@ def fir_filter_bank(x: jax.Array, h: jax.Array, *, block_n: int = 512,
             f"fir_filter_bank: tap_unroll={tap_unroll} invalid for k={k}; "
             f"clamped to {eff}", stacklevel=2)
         tap_unroll = eff
-    pad = k - 1
-    xr = jnp.pad(jnp.real(x).astype(jnp.float32), ((0, 0), (pad, 0)))
-    xi = jnp.pad(jnp.imag(x).astype(jnp.float32), ((0, 0), (pad, 0)))
-    hr = jnp.real(h).astype(jnp.float32)
-    hi = jnp.imag(h).astype(jnp.float32)
+    halo = fir_halo(k)
+    bm = fir_bank_block(m)
+    # [N + halo, M]: `halo` leading zeros make the causal edge a plain read
+    xr = jnp.pad(jnp.real(x).astype(jnp.float32).T, ((halo, 0), (0, 0)))
+    xi = jnp.pad(jnp.imag(x).astype(jnp.float32).T, ((halo, 0), (0, 0)))
+    hr = jnp.real(h).astype(jnp.float32).T                   # [K, M]
+    hi = jnp.imag(h).astype(jnp.float32).T
 
-    grid = (m, n // block_n)
-    halo = block_n + pad
-
-    if hasattr(pl, "Element"):
-        # x blocks OVERLAP (K-1 halo), so the sample dim uses pl.Element
-        # indexing: block j covers elements [j*block_n, j*block_n + halo).
-        def x_map(i, j):
-            return (i, j * block_n)  # (block row, ELEMENT column start)
-
-        x_spec = pl.BlockSpec((1, pl.Element(halo, (0, pad))), x_map)
-        whole_row = False
-    else:
-        # older Pallas: no Element indexing for overlapping blocks — keep the
-        # whole padded row in VMEM ((N+K-1)*4B per plane, ~16 KB at the paper
-        # shapes) and slice the halo window inside the kernel.
-        x_spec = pl.BlockSpec((1, n + pad), lambda i, j: (i, 0))
-        whole_row = True
-
+    # overlapping windows: element-offset indexing on both dims.  With one
+    # bank block the lane offset is the literal 0, which Mosaic can prove
+    # tile-aligned whatever the bank count.
+    x_spec = pl.BlockSpec(
+        (pl.Element(block_n + halo), pl.Element(bm)),
+        lambda i, j: (j * block_n, 0 if bm == m else i * bm))
+    h_spec = pl.BlockSpec((k, bm), lambda i, j: (0, i))
+    y_spec = pl.BlockSpec((block_n, bm), lambda i, j: (j, i))
     yr, yi = pl.pallas_call(
-        functools.partial(_fir_kernel, n_taps=k, block_n=block_n,
-                          tap_unroll=tap_unroll, whole_row=whole_row),
-        grid=grid,
-        in_specs=[
-            x_spec,
-            x_spec,
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-        ],
+        functools.partial(_fir_kernel, n_taps=k, halo=halo, block_n=block_n,
+                          tap_unroll=tap_unroll),
+        grid=(m // bm, n // block_n),
+        in_specs=[x_spec, x_spec, h_spec, h_spec],
+        out_specs=[y_spec, y_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct((m, n), jnp.float32),
+            jax.ShapeDtypeStruct((n, m), jnp.float32),
+            jax.ShapeDtypeStruct((n, m), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr, xi, hr, hi)
-    return (yr + 1j * yi).astype(jnp.complex64)
+    return (yr.T + 1j * yi.T).astype(jnp.complex64)

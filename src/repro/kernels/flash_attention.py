@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -39,12 +41,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, seq_len: int, block_q: int,
 
     def body(ik, carry):
         m, l, acc = carry
-        # leading batch dim sliced (not int-indexed): int indices in pl.load
-        # tuples are rejected by some Pallas versions
-        k = pl.load(k_ref, (slice(0, 1), pl.ds(ik * block_k, block_k),
-                            slice(None)))[0]
-        v = pl.load(v_ref, (slice(0, 1), pl.ds(ik * block_k, block_k),
-                            slice(None)))[0]
+        k = k_ref[0, pl.ds(ik * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ik * block_k, block_k), :]
         k_pos = ik * block_k + jax.lax.iota(jnp.int32, block_k)
         s = jnp.dot(q, k.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32)        # [bq, bk]
@@ -73,7 +71,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, seq_len: int, block_q: int,
                                              "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, block_q: int = 256,
-                    block_k: int = 512, interpret: bool = True) -> jax.Array:
+                    block_k: int = 512,
+                    interpret: bool | None = None) -> jax.Array:
     """q: [B, Hq, S, D]; k/v: [B, Hkv, S, D] -> [B, Hq, S, D]."""
     b, hq, s, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -107,6 +106,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda h, iq: (h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, s, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, hq, s, d)

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _mriq_kernel(xyz_ref, traj_ref, qr_ref, qi_ref):
     # xyz: [block_x, 4] (x, y, z, 0); traj: [4, block_k] rows (kx, ky, kz, 0)
@@ -36,18 +38,23 @@ def _mriq_kernel(xyz_ref, traj_ref, qr_ref, qi_ref):
     xyz = xyz_ref[...]                               # [bx, 4]
     traj = traj_ref[...]                             # [4, bk]
     # traj row 3 is phiMag, but xyz col 3 is zero, so the matmul ignores it.
-    phase = 2.0 * jnp.pi * jnp.dot(xyz, traj,
+    # All three contractions at HIGHEST: Mosaic's default takes float32
+    # operands in one bfloat16 MXU pass, which leaves the result 6.7e-03 off
+    # a float32 reference on a v5e (2.3e-03 with only the phase at HIGHEST;
+    # 2.8e-07 with all three, for 1.39x the kernel time).
+    hi = jax.lax.Precision.HIGHEST
+    phase = 2.0 * jnp.pi * jnp.dot(xyz, traj, precision=hi,
                                    preferred_element_type=jnp.float32)
     pm = traj[3, :]                                  # [bk]
-    qr_ref[...] += jnp.dot(jnp.cos(phase), pm[:, None],
+    qr_ref[...] += jnp.dot(jnp.cos(phase), pm[:, None], precision=hi,
                            preferred_element_type=jnp.float32)
-    qi_ref[...] += jnp.dot(jnp.sin(phase), pm[:, None],
+    qi_ref[...] += jnp.dot(jnp.sin(phase), pm[:, None], precision=hi,
                            preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_x", "block_k", "interpret"))
 def mriq_compute_q(x, y, z, kx, ky, kz, phi_mag, *, block_x: int = 256,
-                   block_k: int = 512, interpret: bool = True):
+                   block_k: int = 512, interpret: bool | None = None):
     """All inputs f32 1-D.  Returns (Q_re [numX], Q_im [numX]).
 
     VMEM per step: bx*4 + 4*bk + bx*bk (phase tile) floats
@@ -79,6 +86,6 @@ def mriq_compute_q(x, y, z, kx, ky, kz, phi_mag, *, block_x: int = 256,
             jax.ShapeDtypeStruct((num_x + px, 1), jnp.float32),
             jax.ShapeDtypeStruct((num_x + px, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xyz, traj)
     return qr[:num_x, 0], qi[:num_x, 0]

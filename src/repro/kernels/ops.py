@@ -1,9 +1,9 @@
 """Public jit'd kernel wrappers + registration of the `pallas` region
 variants for the offload planner.
 
-``INTERPRET`` defaults to True (this container is CPU-only; Mosaic lowering
-needs a real TPU).  On TPU deploys set ``repro.kernels.ops.INTERPRET = False``
-or the REPRO_PALLAS_INTERPRET=0 env var.
+The kernels pick their execution mode from the backend (see
+``repro.kernels.resolve_interpret``): compiled by Mosaic on a TPU,
+interpreted elsewhere.
 
 Tile knobs are exposed uniformly with a ``0`` sentinel meaning "auto from
 shape" (the pre-tuning heuristic, and each knob's declared TuningSpace
@@ -13,8 +13,6 @@ the same gene).  Nonzero knobs are clamped to the nearest legal divisor
 propose any point and still gets a correct, measurable kernel.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +25,6 @@ from repro.kernels.mriq import mriq_compute_q
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssm_scan import ssm_scan
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _dim(args, idx: int, axis: int):
@@ -73,7 +69,7 @@ def attn_core_pallas(q, k, v, *, causal=True, window=0,
     bk = (largest_divisor(sk, block_k) if block_k
           else 512 if sk % 512 == 0 else (sk if sk <= 512 else 8))
     return flash_attention(q, k, v, causal=causal, window=window,
-                           block_q=bq, block_k=bk, interpret=INTERPRET)
+                           block_q=bq, block_k=bk)
 
 
 @register_variant("rglru_scan", "pallas", tuning=TuningSpace(
@@ -84,9 +80,7 @@ def rglru_scan_pallas(a, b, h0, *, block_c=0, time_chunk=0):
           else 128 if a.shape[-1] % 128 == 0 else a.shape[-1])
     tc = (largest_divisor(a.shape[1], time_chunk) if time_chunk
           else 128 if a.shape[1] % 128 == 0 else a.shape[1])
-    h_all, h_f = rglru_scan(a, b, h0, block_c=bc, time_chunk=tc,
-                            interpret=INTERPRET)
-    return h_all, h_f
+    return rglru_scan(a, b, h0, block_c=bc, time_chunk=tc)
 
 
 @register_variant("ssm_scan", "pallas", tuning=TuningSpace(
@@ -97,13 +91,12 @@ def ssm_scan_pallas(a, bx, c, h0, *, block_c=0, time_chunk=0):
           else 128 if a.shape[2] % 128 == 0 else a.shape[2])
     tc = (largest_divisor(a.shape[1], time_chunk) if time_chunk
           else 64 if a.shape[1] % 64 == 0 else a.shape[1])
-    return ssm_scan(a, bx, c, h0, block_c=bc, time_chunk=tc,
-                    interpret=INTERPRET)
+    return ssm_scan(a, bx, c, h0, block_c=bc, time_chunk=tc)
 
 
 @register_variant("rmsnorm", "pallas")
 def rmsnorm_pallas(x, w, eps=1e-6):
-    return rmsnorm(x, w, eps=eps, interpret=INTERPRET)
+    return rmsnorm(x, w, eps=eps)
 
 
 @register_variant("decode_attn", "ref")
@@ -138,8 +131,8 @@ def decode_attn_pallas(q, k_cache, v_cache, slot_pos, cur_pos, *,
     # the kernel itself clamps block_k to s and pads the cache to a
     # multiple, so every proposed point is legal (no validity predicate)
     return decode_attention(q, k_cache, v_cache, slot_pos, cur_pos,
-                            window=window, block_k=bk, interpret=INTERPRET)
+                            window=window, block_k=bk)
 
 
 __all__ = ["decode_attention", "fir_filter_bank", "flash_attention",
-           "mriq_compute_q", "rglru_scan", "rmsnorm", "ssm_scan", "INTERPRET"]
+           "mriq_compute_q", "rglru_scan", "rmsnorm", "ssm_scan"]

@@ -1,10 +1,13 @@
 """RG-LRU linear-recurrence Pallas kernel (recurrentgemma).
 
 h_t = a_t * h_{t-1} + b_t, diagonal over channels.  Grid: (batch, channel
-blocks); the kernel walks time sequentially in VMEM (the recurrence is
-latency-bound, not MXU work — on TPU the win is keeping the whole [T, bc]
-tile resident in VMEM instead of T separate HBM round-trips, exactly the
-Griffin production approach).  Channel blocks are lane-aligned (128).
+blocks, time chunks), time innermost and sequential; the state [1, bc]
+stays in VMEM scratch across the time chunks of one channel block (the
+recurrence is latency-bound, not MXU work — on TPU the win is keeping the
+[T, bc] tiles resident in VMEM instead of T separate HBM round-trips,
+exactly the Griffin production approach).  Each step reads and writes one
+row of the tiles through the refs (ref-level indexing: Mosaic has no
+value-level dynamic slice).  Channel blocks are lane-aligned (128).
 """
 from __future__ import annotations
 
@@ -13,62 +16,60 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
-def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, hf_ref, *, seq_len: int,
+def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, hf_ref, h_ref, *,
                   time_chunk: int):
-    h = h0_ref[0].astype(jnp.float32)                      # [bc]
+    # float32 a/b/y: [1, T, bc]; h0/hf: [1, 1, bc]
+    tc = pl.program_id(2)
 
-    def chunk_body(tc, h):
-        a_c = pl.load(a_ref, (slice(0, 1), pl.ds(tc * time_chunk, time_chunk),
-                              slice(None)))[0].astype(jnp.float32)
-        b_c = pl.load(b_ref, (slice(0, 1), pl.ds(tc * time_chunk, time_chunk),
-                              slice(None)))[0].astype(jnp.float32)
+    @pl.when(tc == 0)
+    def _init():
+        h_ref[...] = h0_ref[0]
 
-        def step(t, carry):
-            h, out = carry
-            h = a_c[t] * h + b_c[t]
-            out = jax.lax.dynamic_update_index_in_dim(out, h, t, 0)
-            return h, out
-
-        out0 = jnp.zeros((time_chunk, h.shape[-1]), jnp.float32)
-        h, out = jax.lax.fori_loop(0, time_chunk, step, (h, out0))
-        pl.store(y_ref, (slice(0, 1), pl.ds(tc * time_chunk, time_chunk),
-                         slice(None)), out.astype(y_ref.dtype)[None])
+    def step(t, h):
+        h = a_ref[0, pl.ds(t, 1), :] * h + b_ref[0, pl.ds(t, 1), :]  # [1, bc]
+        y_ref[0, pl.ds(t, 1), :] = h
         return h
 
-    h = jax.lax.fori_loop(0, seq_len // time_chunk, chunk_body, h)
-    hf_ref[0] = h.astype(hf_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, time_chunk, step, h_ref[...])
+
+    @pl.when(tc == pl.num_programs(2) - 1)
+    def _finish():
+        hf_ref[0] = h_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "time_chunk", "interpret"))
 def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *, block_c: int = 128,
-               time_chunk: int = 128, interpret: bool = True):
-    """a, b: [B, S, D]; h0: [B, D] -> (h_all [B, S, D], h_final [B, D]).
+               time_chunk: int = 128, interpret: bool | None = None):
+    """a, b: [B, S, D]; h0: [B, D] -> (h_all [B, S, D], h_final [B, D]
+    float32).
 
-    VMEM per step: 2 * time_chunk * block_c * 4B (a, b chunks) + carry."""
+    VMEM per step: 3 * time_chunk * block_c float32 tiles (a, b, y) + the
+    state; double-buffered at the defaults ~= 0.4 MB."""
     bsz, s, d = a.shape
     block_c = min(block_c, d)
     time_chunk = min(time_chunk, s)
     assert d % block_c == 0 and s % time_chunk == 0
 
-    grid = (bsz, d // block_c)
+    # float32 tiles: Mosaic stores single rows only into 32-bit tiles.  The
+    # casts run as XLA passes over HBM before the kernel.
+    f32 = jnp.float32
+    tile = pl.BlockSpec((1, time_chunk, block_c), lambda i, j, t: (i, t, j))
+    state = pl.BlockSpec((1, 1, block_c), lambda i, j, t: (i, 0, j))
     y, hf = pl.pallas_call(
-        functools.partial(_rglru_kernel, seq_len=s, time_chunk=time_chunk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, s, block_c), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, s, block_c), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s, block_c), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
-        ],
+        functools.partial(_rglru_kernel, time_chunk=time_chunk),
+        grid=(bsz, d // block_c, s // time_chunk),
+        in_specs=[tile, tile, state],
+        out_specs=[tile, state],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, d), a.dtype),
-            jax.ShapeDtypeStruct((bsz, d), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, s, d), f32),
+            jax.ShapeDtypeStruct((bsz, 1, d), f32),
         ],
-        interpret=interpret,
-    )(a, b, h0)
-    return y, hf
+        scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+    )(a.astype(f32), b.astype(f32), h0[:, None, :].astype(f32))
+    return y.astype(a.dtype), hf[:, 0]

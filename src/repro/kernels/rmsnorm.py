@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                       # [br, d]
@@ -19,7 +21,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "eps", "interpret"))
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool | None = None) -> jax.Array:
     """x: [..., D]; w: [D]."""
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -40,6 +42,6 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, w)
     return out[:rows].reshape(orig_shape)

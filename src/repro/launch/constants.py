@@ -1,25 +1,19 @@
-"""Target-hardware constants (TPU v5e class, per assignment)."""
+"""Roofline projection onto the target chip (peaks in ``repro.hw``)."""
+from __future__ import annotations
 
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link
-VMEM_BYTES = 16 * 1024 * 1024  # per core
-HBM_BYTES = 16 * 1024**3       # per chip
-TRANSCENDENTAL_RATE = 1.0e12   # elem/s (VPU transcendental retire rate, approx)
-
-SINGLE_POD_CHIPS = 256
-MULTI_POD_CHIPS = 512
+from repro.hw import TARGET, TRANSCENDENTAL_RATE
 
 
 def projected_tpu_seconds(flops: float, hbm_bytes: float,
                           transcendentals: float = 0.0,
                           collective_bytes: float = 0.0,
                           chips: int = 1) -> dict:
-    """Three-term roofline time for a per-chip workload (seconds)."""
-    compute = flops / (chips * PEAK_FLOPS_BF16)
-    memory = hbm_bytes / (chips * HBM_BW)
+    """Three-term roofline time for a per-chip workload on the target chip
+    (seconds)."""
+    compute = flops / (chips * TARGET.bf16_flops)
+    memory = hbm_bytes / (chips * TARGET.hbm_bw)
     trans = transcendentals / (chips * TRANSCENDENTAL_RATE)
-    coll = collective_bytes / (chips * ICI_BW)
+    coll = collective_bytes / (chips * TARGET.ici_bw)
     terms = {"compute": compute, "memory": memory, "transcendental": trans,
              "collective": coll}
     bottleneck = max(terms, key=terms.get)

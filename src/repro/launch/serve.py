@@ -17,17 +17,18 @@ from __future__ import annotations
 import argparse
 import os
 import time
+from dataclasses import dataclass
 
 import jax
 
 from repro.configs import get_config
 from repro.core.plan_cache import (DEFAULT_CACHE_ENV, DEFAULT_CACHE_PATH,
                                    PlanCache)
-from repro.core.regions import Impl
 from repro.core.strategies import STRATEGY_NAMES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import factory as F
 from repro.serving.engine import ServeEngine
-from repro.serving.sampling import SamplingParams
+from repro.serving.sampling import GREEDY, SamplingParams
 
 
 def make_offloader(reps: int = 2, strategy: str = "staged", seed: int = 0,
@@ -41,28 +42,20 @@ def make_offloader(reps: int = 2, strategy: str = "staged", seed: int = 0,
         verify_workers=verify_workers, tune_tiles=tune_tiles))
 
 
-def planned_impl(arch: str, cache: PlanCache, reps: int = 2,
-                 strategy: str = "staged", seed: int = 0,
-                 verify_workers: int = 1, tune_tiles: bool = False,
-                 offloader=None) -> Impl:
-    """Best cached/measured offload pattern for the arch's block regions,
-    merged over the architectural defaults.  ``tune_tiles`` widens the
-    search genome to (variant, tile params) — see docs/search-strategies.md
-    "Kernel autotuning".  Pass ``offloader`` to share one instance (and its
-    CompileCache) with an online replanner."""
+def plan_offload(arch: str, cache: PlanCache, offloader):
+    """Best cached/measured offload pattern for the arch's block regions
+    (the ``--auto-offload`` path).  Returns the ``PlanReport``; its
+    ``best_impl()`` is served merged over the architectural defaults.
+    ``offloader`` is shared with an online replanner when there is one
+    (its CompileCache stays warm across searches)."""
     from repro.models.offload_program import make_lm_program
 
-    prog = make_lm_program(arch)
-    if offloader is None:
-        offloader = make_offloader(reps=reps, strategy=strategy, seed=seed,
-                                   verify_workers=verify_workers,
-                                   tune_tiles=tune_tiles)
-    report = offloader.plan(prog, cache=cache)
+    report = offloader.plan(make_lm_program(arch), cache=cache)
     src = ("plan cache" if report.from_cache
            else f"measured search [{report.strategy}]")
     print(f"auto-offload [{src}]: {report.best_pattern or 'all-ref'} "
           f"(speedup {report.speedup:.2f}x)")
-    return Impl(report.best_pattern)
+    return report
 
 
 def make_replan_fn(arch: str, offloader, cache: PlanCache,
@@ -81,6 +74,42 @@ def make_replan_fn(arch: str, offloader, cache: PlanCache,
                                plan_extra=dict(conditions))
         return offloader.plan(prog, cache=cache)
     return plan_fn
+
+
+@dataclass
+class ServeRun:
+    """What one :func:`serve` call did: the engine (its ``stats()`` hold
+    the counters), the finished requests and the wall seconds they took."""
+    engine: ServeEngine
+    done: list
+    wall_s: float
+
+
+def serve(cfg, params, *, slots: int, prompt_len: int, new_tokens: int,
+          requests: int, seed: int = 0, impl=None,
+          sampling: SamplingParams = GREEDY, vary_lengths: bool = False,
+          replanner=None) -> ServeRun:
+    """Serve ``requests`` synthetic requests (drawn from ``seed``) on a
+    fresh ``ServeEngine`` until all finish.  ``vary_lengths`` staggers
+    prompt lengths over four steps down from ``prompt_len`` so that
+    several prefill buckets are exercised."""
+    ctx = prompt_len + new_tokens + cfg.n_front
+    engine = ServeEngine(cfg, params, slots=slots, ctx=ctx, seed=seed,
+                         impl=impl)
+    if replanner is not None:
+        engine.attach_replanner(replanner)
+    key = jax.random.PRNGKey(seed)
+    for r in range(requests):
+        plen = prompt_len
+        if vary_lengths:
+            plen = max(1, prompt_len - (r % 4) * (prompt_len // 4))
+        tokens, frontend = F.synthetic_request(cfg, plen,
+                                               jax.random.fold_in(key, r))
+        engine.submit(tokens, max_new_tokens=new_tokens, sampling=sampling,
+                      frontend=frontend)
+    t0 = time.perf_counter()
+    done = engine.run_to_completion()
+    return ServeRun(engine, done, time.perf_counter() - t0)
 
 
 def main() -> None:
@@ -139,6 +168,7 @@ def main() -> None:
                          "regime (bucket mix, occupancy, decode/prefill "
                          "balance) drifts from the planned one")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -153,13 +183,8 @@ def main() -> None:
                                    tune_tiles=args.tune_tiles)
     impl = None
     if args.auto_offload:
-        impl = planned_impl(args.arch, cache, offloader=offloader)
-    key = jax.random.PRNGKey(args.seed)
-    params = F.init_params(cfg, key)
-    ctx = args.prompt_len + args.new_tokens + cfg.n_front
-
-    engine = ServeEngine(cfg, params, slots=args.slots, ctx=ctx,
-                         seed=args.seed, impl=impl)
+        impl = plan_offload(args.arch, cache, offloader).best_impl()
+    params = F.init_params(cfg, jax.random.PRNGKey(args.seed))
     replanner = None
     if replanning:
         from repro.serving.replan import Replanner, ReplanConfig
@@ -172,29 +197,21 @@ def main() -> None:
             config=ReplanConfig(every_ticks=args.replan_every,
                                 on_drift=args.replan_on_drift),
             quarantine=offloader.quarantine)
-        engine.attach_replanner(replanner)
-    sampling = SamplingParams(temperature=args.temperature, top_k=args.top_k)
-    for r in range(args.requests):
-        plen = args.prompt_len
-        if args.vary_lengths:
-            plen = max(1, args.prompt_len - (r % 4) * (args.prompt_len // 4))
-        tokens, frontend = F.synthetic_request(cfg, plen,
-                                               jax.random.fold_in(key, r))
-        engine.submit(tokens, max_new_tokens=args.new_tokens,
-                      sampling=sampling, frontend=frontend)
-
-    t0 = time.perf_counter()
-    done = engine.run_to_completion()
-    wall = time.perf_counter() - t0
-    s = engine.stats()
-    for req in done:
+    run = serve(cfg, params, slots=args.slots, prompt_len=args.prompt_len,
+                new_tokens=args.new_tokens, requests=args.requests,
+                seed=args.seed, impl=impl,
+                sampling=SamplingParams(temperature=args.temperature,
+                                        top_k=args.top_k),
+                vary_lengths=args.vary_lengths, replanner=replanner)
+    s = run.engine.stats()
+    for req in run.done:
         print(f"req {req.rid}: prompt {req.tokens.size:4d} "
               f"(bucket {req.bucket:4d}) | wait {req.queue_wait_s*1e3:7.1f} ms "
               f"| ttft {req.ttft_s*1e3:7.1f} ms | decode "
               f"{req.decode_tps:8.1f} tok/s")
     print(f"served {s['requests_finished']} requests / "
-          f"{s['generated_tokens']} tokens in {wall:.2f} s "
-          f"({s['generated_tokens']/wall:.1f} tok/s aggregate)")
+          f"{s['generated_tokens']} tokens in {run.wall_s:.2f} s "
+          f"({s['generated_tokens']/run.wall_s:.1f} tok/s aggregate)")
     print(f"prefill compilations: {s['prefill_traces']} "
           f"(buckets {s['buckets']})")
     if replanner is not None:
@@ -206,7 +223,7 @@ def main() -> None:
         if rs["canary_rejects"] or s["rollbacks"]:
             print(f"fault tolerance: {rs['canary_rejects']} canary "
                   f"reject(s), {s['rollbacks']} rollback(s)"
-                  + (f" [degraded: {engine.last_fault}]"
+                  + (f" [degraded: {run.engine.last_fault}]"
                      if s["degraded"] else ""))
         if replanner.last_error is not None:
             print(f"replanner error: {replanner.last_error}")

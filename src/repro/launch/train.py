@@ -20,6 +20,7 @@ import jax
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import SHAPES, get_config
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim.schedule import cosine_with_warmup
 from repro.parallel.presets import parallelism_for
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 production mesh (TPU slice)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

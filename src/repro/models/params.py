@@ -12,6 +12,7 @@ This keeps shapes, initializers and sharding axes from drifting apart.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -41,8 +42,21 @@ def _is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _draw_normal(key: jax.Array, shape: tuple[int, ...], dtype: str,
+                 scale: jax.Array, denom: jax.Array) -> jax.Array:
+    """One leaf's normal draw, scaled and cast inside one jit: the float32
+    draw never exists as a whole array (XLA fuses it into the cast), so a
+    stacked full-width leaf needs only its own bytes on the device.
+    ``scale`` and ``denom`` are traced so that XLA keeps the division
+    (a constant divisor would become a reciprocal multiply and change the
+    values)."""
+    x = jax.random.normal(key, shape, jnp.float32)
+    return (x * scale / denom).astype(jnp.dtype(dtype))
+
+
 def init(template, key: jax.Array):
-    """Materialize a template into real arrays (used for reduced configs)."""
+    """Materialize a template into real arrays."""
     leaves, treedef = jax.tree.flatten(template, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
     out = []
@@ -61,10 +75,12 @@ def init(template, key: jax.Array):
             arr = a.astype(dt)
         elif s.init == "scaled":
             fan_in = s.shape[0] if len(s.shape) >= 2 else max(int(np.prod(s.shape)), 1)
-            arr = (jax.random.normal(k, s.shape, jnp.float32) / np.sqrt(fan_in)).astype(dt)
+            arr = _draw_normal(k, s.shape, s.dtype, jnp.float32(1.0),
+                               jnp.float32(np.sqrt(fan_in)))
         else:  # normal
             fan_in = s.shape[-2] if len(s.shape) >= 2 else max(s.shape[-1], 1)
-            arr = (jax.random.normal(k, s.shape, jnp.float32) * s.scale / np.sqrt(fan_in)).astype(dt)
+            arr = _draw_normal(k, s.shape, s.dtype, jnp.float32(s.scale),
+                               jnp.float32(np.sqrt(fan_in)))
         out.append(arr)
     return jax.tree.unflatten(treedef, out)
 

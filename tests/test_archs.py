@@ -23,6 +23,34 @@ def configs():
     return all_configs()
 
 
+def test_init_params_values_unchanged_on_reduced_config():
+    """The per-leaf jitted draw gives bit-for-bit the values of the eager
+    draw it replaced (float32 normal, times scale, over sqrt(fan_in), then
+    the cast) on a reduced config."""
+    from repro.models.params import _is_spec
+
+    cfg = get_config("falcon-mamba-7b").reduced()
+    template = F.template(cfg)
+    specs, _ = jax.tree.flatten(template, is_leaf=_is_spec)
+    keys = jax.random.split(KEY, len(specs))
+    got = jax.tree.leaves(F.init_params(cfg, KEY))
+    checked = 0
+    for k, s, arr in zip(keys, specs, got):
+        if s.init not in ("normal", "scaled"):
+            continue
+        if s.init == "scaled":
+            fan_in = s.shape[0] if len(s.shape) >= 2 else int(np.prod(s.shape))
+            want = jax.random.normal(k, s.shape, jnp.float32) / np.sqrt(fan_in)
+        else:
+            fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
+            want = (jax.random.normal(k, s.shape, jnp.float32) * s.scale
+                    / np.sqrt(fan_in))
+        np.testing.assert_array_equal(np.asarray(arr),
+                                      np.asarray(want.astype(s.dtype)))
+        checked += 1
+    assert checked >= 5
+
+
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_forward_and_loss(arch, configs):
     cfg = configs[arch].reduced()

@@ -1,6 +1,5 @@
-"""Per-kernel allclose sweeps vs the pure-jnp oracles (shapes x dtypes),
-exactly as the deliverable requires: every Pallas kernel in interpret mode
-against ref.py."""
+"""Per-kernel allclose sweeps vs the pure-jnp oracles (shapes x dtypes):
+every Pallas kernel, explicitly in interpret mode, against ref.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +14,14 @@ from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssm_scan import ssm_scan
 
 KEY = jax.random.PRNGKey(0)
+
+
+def test_interpret_mode_comes_from_the_backend(monkeypatch):
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,17 @@ def test_ai_counts_matmul_flops_exactly():
     assert ana.boundary_bytes == 4 * (64 * 128 + 128 * 128 + 64 * 128)
 
 
+def test_ai_counts_flops_inside_a_nested_jit():
+    # an einsum (and silu) traced inside a jit appears as one `jit` eqn
+    # whose sub-jaxpr holds the dot: its FLOPs must still be counted
+    inner = jax.jit(lambda a, b: jax.nn.silu(jnp.einsum("ij,jk->ik", a, b)))
+    x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
+    w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
+    ana = analyze_region(lambda a, b: inner(a, b), x, w)
+    assert ana.flops >= 2 * 64 * 128 * 128
+    assert "jit" not in ana.unclassified
+
+
 def test_ai_multiplies_scan_trip_count():
     def f(x):
         def body(c, _):
