@@ -47,6 +47,7 @@ from concurrent.futures import TimeoutError as FutureTimeout
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from repro import obs
 from repro.core import search  # module ref: monkeypatched fns stay honored
 
 
@@ -308,13 +309,16 @@ class VerificationExecutor:
         """The (deduped) ``(future, fresh)`` compiling ``job``.  Tracing/
         lowering (GIL-bound Python) runs here on the driver thread; only
         the XLA compile (which releases the GIL) goes to the worker pool —
-        concurrency where it can exist, no GIL thrash where it can't."""
+        concurrency where it can exist, no GIL thrash where it can't.  The
+        spans are ``planner.lower`` and ``planner.compile``."""
         def submit() -> Future:
             with self._lock:
                 self.stats.compiled += 1
-            lowered, lower_s, err = search.aot_lower(job.fn, job.args)
-            return self._ensure_pool().submit(search.finish_compile,
-                                              lowered, lower_s, err)
+            with obs.span("planner.lower", pattern=job.pattern):
+                lowered, lower_s, err = search.aot_lower(job.fn, job.args)
+            return self._ensure_pool().submit(search.compile_lowered,
+                                              lowered, lower_s, err,
+                                              job.pattern)
         fut, fresh = self.cache.get_or_submit(job.key, submit)
         with self._lock:
             if fresh:

@@ -45,6 +45,7 @@ from dataclasses import dataclass, field
 
 import jax
 
+from repro import obs
 from repro.core import search
 from repro.core.cost_model import CostModel
 from repro.core.executor import (CompileCache, FaultPolicy,
@@ -416,18 +417,27 @@ class AutoOffloader:
 
         Returns a ``PlanReport``; ``report.best_impl()`` is the
         dispatchable selected pattern.
+
+        The whole call is the span ``planner.plan`` (attributes ``program``
+        and ``from_cache``); a search opens one span per step under it:
+        ``planner.analyze``, ``planner.intensity``, ``planner.resources``,
+        ``planner.search`` and ``planner.select``.
         """
-        store = resolve_cache(cache)
-        ckey = plan_cache_key(program, self.config) if store is not None else ""
-        if store is not None:
-            entry = store.get(ckey)
-            if entry is not None:
-                return self._report_from_cache(program, ckey, entry)
-        report = self._plan_measured(program, key, store=store)
-        report.cache_key = ckey
-        if store is not None and self._sound(report):
-            store.put(ckey, self._cache_entry(report, program))
-        return report
+        with obs.span("planner.plan", program=program.name) as sp:
+            store = resolve_cache(cache)
+            ckey = (plan_cache_key(program, self.config)
+                    if store is not None else "")
+            if store is not None:
+                entry = store.get(ckey)
+                if entry is not None:
+                    sp.set(from_cache=True)
+                    return self._report_from_cache(program, ckey, entry)
+            sp.set(from_cache=False)
+            report = self._plan_measured(program, key, store=store)
+            report.cache_key = ckey
+            if store is not None and self._sound(report):
+                store.put(ckey, self._cache_entry(report, program))
+            return report
 
     @staticmethod
     def _sound(report: PlanReport) -> bool:
@@ -448,28 +458,30 @@ class AutoOffloader:
                        store: "PlanCache | None" = None) -> PlanReport:
         cfg = self.config
         key = key if key is not None else jax.random.PRNGKey(0)
-        sample = program.sample_inputs(key)
 
         # ---- Step 1: code analysis ------------------------------------
-        full_ref = program.build(Impl())
-        try:
-            jaxpr_loops = count_loops(full_ref, *sample)
-        except Exception:  # noqa: BLE001 — census is advisory; a broken
-            jaxpr_loops = 0  # all-ref build is recorded by the baseline
-                             # measurement below, not raised out of plan()
-        report = PlanReport(program=program.name,
-                            source_loop_count=program.source_loop_count,
-                            jaxpr_loop_count=jaxpr_loops)
+        with obs.span("planner.analyze"):
+            sample = program.sample_inputs(key)
+            full_ref = program.build(Impl())
+            try:
+                jaxpr_loops = count_loops(full_ref, *sample)
+            except Exception:  # noqa: BLE001 — census is advisory; a broken
+                jaxpr_loops = 0  # all-ref build is recorded by the baseline
+                                 # measurement below, not raised out of plan()
+            report = PlanReport(program=program.name,
+                                source_loop_count=program.source_loop_count,
+                                jaxpr_loop_count=jaxpr_loops)
 
         # ---- Step 2: arithmetic-intensity filter ----------------------
-        cands: list[CandidateInfo] = []
-        for r in program.regions:
-            ana = analyze_region(r.analysis_fn, *r.analysis_args, name=r.name)
-            cands.append(CandidateInfo(region=r.name, analysis=ana))
-        report.candidates = cands
-        by_ai = sorted(cands, key=lambda c: -c.analysis.arithmetic_intensity)
-        ai_set = [c.region for c in by_ai[:cfg.top_a]]
-        report.ai_selected = ai_set
+        with obs.span("planner.intensity"):
+            cands: list[CandidateInfo] = []
+            for r in program.regions:
+                ana = analyze_region(r.analysis_fn, *r.analysis_args, name=r.name)
+                cands.append(CandidateInfo(region=r.name, analysis=ana))
+            report.candidates = cands
+            by_ai = sorted(cands, key=lambda c: -c.analysis.arithmetic_intensity)
+            ai_set = [c.region for c in by_ai[:cfg.top_a]]
+            report.ai_selected = ai_set
 
         # ---- Step 3: resource filter over (region, variant) pairs -----
         # the cheap lowering of EVERY (region, variant) pair fans out on the
@@ -493,228 +505,231 @@ class AutoOffloader:
         if store is not None:
             quarantine.load_records(store.quarantine_for(mkey))
         try:
-            region_map = {r.name: r for r in program.regions}
-            pairs: list[VariantCandidate] = []
-            lower_jobs: list[tuple] = []
-            lower_meta: list[tuple] = []
-            for c in cands:
-                if c.region not in ai_set:
-                    continue
-                r = region_map[c.region]
-                for var, fn in offload_variants(c.region).items():
-                    lower_jobs.append((c.region, var, fn, r.analysis_args,
-                                       r.static_kwargs))
-                    lower_meta.append((c, var))
-            for (c, var), est in zip(
-                    lower_meta,
-                    precompile_many(lower_jobs, mapper=executor.map_concurrent)):
-                c.variant_estimates[var] = est
-                pairs.append(VariantCandidate(c.region, var, c.analysis, est))
-            eligible = [p for p in pairs if p.resources.lower_ok
-                        and p.resources.resource_fraction <= cfg.resource_cap]
-            # quarantined (region, variant) pairs never re-enter the
-            # ranking: their past permanent failures already cost budget
-            eligible = [p for p in eligible
-                        if not quarantine.is_quarantined(p.region, p.variant)]
+            with obs.span("planner.resources"):
+                region_map = {r.name: r for r in program.regions}
+                pairs: list[VariantCandidate] = []
+                lower_jobs: list[tuple] = []
+                lower_meta: list[tuple] = []
+                for c in cands:
+                    if c.region not in ai_set:
+                        continue
+                    r = region_map[c.region]
+                    for var, fn in offload_variants(c.region).items():
+                        lower_jobs.append((c.region, var, fn, r.analysis_args,
+                                           r.static_kwargs))
+                        lower_meta.append((c, var))
+                for (c, var), est in zip(
+                        lower_meta,
+                        precompile_many(lower_jobs, mapper=executor.map_concurrent)):
+                    c.variant_estimates[var] = est
+                    pairs.append(VariantCandidate(c.region, var, c.analysis, est))
+                eligible = [p for p in pairs if p.resources.lower_ok
+                            and p.resources.resource_fraction <= cfg.resource_cap]
+                # quarantined (region, variant) pairs never re-enter the
+                # ranking: their past permanent failures already cost budget
+                eligible = [p for p in eligible
+                            if not quarantine.is_quarantined(p.region, p.variant)]
 
-            def rank_key(p: VariantCandidate):
-                # efficiency first; the region's declared deploy/measure
-                # preference breaks ties (equal AI + equal fraction is common
-                # for same-shaped variants)
-                r = region_map[p.region]
-                preferred = p.variant in (r.deploy_variant, r.measure_variant)
-                return (-p.efficiency, 0 if preferred else 1, p.variant)
+                def rank_key(p: VariantCandidate):
+                    # efficiency first; the region's declared deploy/measure
+                    # preference breaks ties (equal AI + equal fraction is common
+                    # for same-shaped variants)
+                    r = region_map[p.region]
+                    preferred = p.variant in (r.deploy_variant, r.measure_variant)
+                    return (-p.efficiency, 0 if preferred else 1, p.variant)
 
-            ranked = sorted(eligible, key=rank_key)
+                ranked = sorted(eligible, key=rank_key)
 
-            # per-region variant ranking; top-c regions by their best pair
-            variants_of: dict[str, list[VariantCandidate]] = {}
-            for p in ranked:
-                variants_of.setdefault(p.region, []).append(p)
-            eff_regions: list[str] = []
-            for p in ranked:
-                if p.region not in eff_regions:
-                    eff_regions.append(p.region)
-                if len(eff_regions) == cfg.top_c:
-                    break
-            report.eff_selected = eff_regions
-            report.eff_pairs = [(p.region, p.variant) for p in ranked
-                                if p.region in eff_regions]
-            for c in cands:                         # mirror best pair for reports
-                best = variants_of.get(c.region, [])
-                if best:
-                    c.best_variant = best[0].variant
-                    c.resources = best[0].resources
-                elif c.variant_estimates:           # all failed/over-cap: show one
-                    c.resources = next(iter(c.variant_estimates.values()))
+                # per-region variant ranking; top-c regions by their best pair
+                variants_of: dict[str, list[VariantCandidate]] = {}
+                for p in ranked:
+                    variants_of.setdefault(p.region, []).append(p)
+                eff_regions: list[str] = []
+                for p in ranked:
+                    if p.region not in eff_regions:
+                        eff_regions.append(p.region)
+                    if len(eff_regions) == cfg.top_c:
+                        break
+                report.eff_selected = eff_regions
+                report.eff_pairs = [(p.region, p.variant) for p in ranked
+                                    if p.region in eff_regions]
+                for c in cands:                         # mirror best pair for reports
+                    best = variants_of.get(c.region, [])
+                    if best:
+                        c.best_variant = best[0].variant
+                        c.resources = best[0].resources
+                    elif c.variant_estimates:           # all failed/over-cap: show one
+                        c.resources = next(iter(c.variant_estimates.values()))
 
             # ---- Step 4: measured pattern search (pluggable strategy) -----
-            # the all-ref baseline goes through the same fault policy as
-            # every candidate: watchdogs when configured, bounded retry for
-            # transients — an unlucky hiccup must not void the whole search
-            report.baseline = measure_with_retry(
-                lambda: (search.time_callable(
-                    full_ref, sample, warmup=cfg.warmup, reps=cfg.reps,
-                    pattern="all-ref", impl=Impl(),
-                    compile_timeout_s=policy.compile_timeout_s,
-                    run_timeout_s=policy.run_timeout_s,
-                    check_finite=policy.check_finite,
-                    outlier_mad=policy.outlier_mad,
-                    remeasure=policy.remeasure), True),
-                policy)
+            with obs.span("planner.search"):
+                # the all-ref baseline goes through the same fault policy as
+                # every candidate: watchdogs when configured, bounded retry for
+                # transients — an unlucky hiccup must not void the whole search
+                report.baseline = measure_with_retry(
+                    lambda: (search.time_callable(
+                        full_ref, sample, warmup=cfg.warmup, reps=cfg.reps,
+                        pattern="all-ref", impl=Impl(),
+                        compile_timeout_s=policy.compile_timeout_s,
+                        run_timeout_s=policy.run_timeout_s,
+                        check_finite=policy.check_finite,
+                        outlier_mad=policy.outlier_mad,
+                        remeasure=policy.remeasure), True),
+                    policy)
 
-            def _job(impl) -> VerifyJob:
-                impl = Impl(impl)
-                return VerifyJob(key=compile_key(program.name, impl, sample),
-                                 fn=program.build(impl), args=sample,
-                                 pattern=impl.describe(), impl=dict(impl))
+                def _job(impl) -> VerifyJob:
+                    impl = Impl(impl)
+                    return VerifyJob(key=compile_key(program.name, impl, sample),
+                                     fn=program.build(impl), args=sample,
+                                     pattern=impl.describe(), impl=dict(impl))
 
-            def measure(impl: Impl) -> Measurement:
-                return executor.measure_one(_job(impl), warmup=cfg.warmup,
-                                            reps=cfg.reps)
+                def measure(impl: Impl) -> Measurement:
+                    return executor.measure_one(_job(impl), warmup=cfg.warmup,
+                                                reps=cfg.reps)
 
-            def measure_batch(impls: list) -> list:
-                return executor.measure_batch([_job(i) for i in impls],
-                                              warmup=cfg.warmup, reps=cfg.reps)
+                def measure_batch(impls: list) -> list:
+                    return executor.measure_batch([_job(i) for i in impls],
+                                                  warmup=cfg.warmup, reps=cfg.reps)
 
-            def prefetch(impls: list) -> None:
-                executor.prefetch([_job(i) for i in impls])
+                def prefetch(impls: list) -> None:
+                    executor.prefetch([_job(i) for i in impls])
 
-            ledger = MeasurementLedger(measure, budget=cfg.max_measurements,
-                                       measure_batch_fn=measure_batch,
-                                       prefetch_fn=prefetch,
-                                       quarantine=quarantine)
-            # cross-run reuse: sibling cache entries measured under the same
-            # conditions donate their per-pattern measurements — a re-proposed
-            # known pattern is served from the ledger and costs zero d
-            primed: list[Measurement] = []
-            if store is not None:
-                for m in store.measurements_for(mkey):
-                    impl = Impl(m.get("impl", {}))
-                    pm = Measurement(
-                        pattern=str(m.get("pattern", impl.describe())),
-                        compile_seconds=float(m.get("compile_seconds", 0.0)),
-                        run_seconds=float(m.get("run_seconds", float("inf"))),
-                        runs=[], ok=bool(m.get("ok", False)),
-                        error=str(m.get("error", "")), impl=dict(impl),
-                        first_run_seconds=float(m.get("first_run_seconds", 0.0)))
-                    ledger.prime(impl, pm)
-                    primed.append(pm)
-            # the all-ref baseline pre-exists (the paper's running CPU system):
-            # a strategy re-proposing it gets the measurement without spending d.
-            # Primed AFTER the cache donations so this run's fresh baseline wins.
-            ledger.prime(Impl(), report.baseline)
+                ledger = MeasurementLedger(measure, budget=cfg.max_measurements,
+                                           measure_batch_fn=measure_batch,
+                                           prefetch_fn=prefetch,
+                                           quarantine=quarantine)
+                # cross-run reuse: sibling cache entries measured under the same
+                # conditions donate their per-pattern measurements — a re-proposed
+                # known pattern is served from the ledger and costs zero d
+                primed: list[Measurement] = []
+                if store is not None:
+                    for m in store.measurements_for(mkey):
+                        impl = Impl(m.get("impl", {}))
+                        pm = Measurement(
+                            pattern=str(m.get("pattern", impl.describe())),
+                            compile_seconds=float(m.get("compile_seconds", 0.0)),
+                            run_seconds=float(m.get("run_seconds", float("inf"))),
+                            runs=[], ok=bool(m.get("ok", False)),
+                            error=str(m.get("error", "")), impl=dict(impl),
+                            first_run_seconds=float(m.get("first_run_seconds", 0.0)))
+                        ledger.prime(impl, pm)
+                        primed.append(pm)
+                # the all-ref baseline pre-exists (the paper's running CPU system):
+                # a strategy re-proposing it gets the measurement without spending d.
+                # Primed AFTER the cache donations so this run's fresh baseline wins.
+                ledger.prime(Impl(), report.baseline)
 
-            def _bound_tuning(p: VariantCandidate):
-                # tile-parameter genes only when the config asks for them
-                # AND the variant declared a space; None keeps the
-                # variant-only trajectory bit-identical
-                if not cfg.tune_tiles:
-                    return None
-                space = tuning_space(p.region, p.variant)
-                if space is None:
-                    return None
-                return BoundTuningSpace(
-                    space, tuple(region_map[p.region].analysis_args))
+                def _bound_tuning(p: VariantCandidate):
+                    # tile-parameter genes only when the config asks for them
+                    # AND the variant declared a space; None keeps the
+                    # variant-only trajectory bit-identical
+                    if not cfg.tune_tiles:
+                        return None
+                    space = tuning_space(p.region, p.variant)
+                    if space is None:
+                        return None
+                    return BoundTuningSpace(
+                        space, tuple(region_map[p.region].analysis_args))
 
-            state = SearchState(
-                regions=eff_regions,
-                ranked=[SearchCandidate(p.region, p.variant,
-                                        p.resources.resource_fraction,
-                                        p.efficiency,
-                                        flops=p.analysis.flops,
-                                        transcendentals=p.analysis.transcendentals,
-                                        boundary_bytes=p.analysis.boundary_bytes,
-                                        alignment=p.analysis.alignment,
-                                        tuning=_bound_tuning(p))
-                        for p in ranked if p.region in eff_regions],
-                resource_cap=cfg.resource_cap,
-                seed=cfg.seed,
-                baseline=report.baseline,
-                quarantine=quarantine)
-            # the roofline surrogate, seeded from the Step-3 estimates and
-            # pre-calibrated on everything already measured: the fresh baseline
-            # (exact re-base), then the primed cross-run measurements —
-            # single-gene patterns first, so their deltas are pinned exactly
-            # before combined patterns distribute their residuals
-            model = CostModel(candidates=state.ranked,
-                              baseline_seconds=report.baseline.run_seconds
-                              if report.baseline.ok else 0.0)
-            # restore persisted calibration (deltas + pair-interaction
-            # corrections) from sibling entries under the same measurement
-            # conditions; this run's own observations below refine it
-            if store is not None:
-                model.load_state(store.cost_model_for(mkey))
-            if report.baseline.ok:
-                model.observe(Impl(), report.baseline.run_seconds)
-            for m in sorted((p for p in primed if p.ok and p.mapping()),
-                            key=lambda m: (len(m.mapping()), m.pattern)):
-                model.observe(Impl(m.mapping()), m.run_seconds)
-            state.cost_model = model
+                state = SearchState(
+                    regions=eff_regions,
+                    ranked=[SearchCandidate(p.region, p.variant,
+                                            p.resources.resource_fraction,
+                                            p.efficiency,
+                                            flops=p.analysis.flops,
+                                            transcendentals=p.analysis.transcendentals,
+                                            boundary_bytes=p.analysis.boundary_bytes,
+                                            alignment=p.analysis.alignment,
+                                            tuning=_bound_tuning(p))
+                            for p in ranked if p.region in eff_regions],
+                    resource_cap=cfg.resource_cap,
+                    seed=cfg.seed,
+                    baseline=report.baseline,
+                    quarantine=quarantine)
+                # the roofline surrogate, seeded from the Step-3 estimates and
+                # pre-calibrated on everything already measured: the fresh baseline
+                # (exact re-base), then the primed cross-run measurements —
+                # single-gene patterns first, so their deltas are pinned exactly
+                # before combined patterns distribute their residuals
+                model = CostModel(candidates=state.ranked,
+                                  baseline_seconds=report.baseline.run_seconds
+                                  if report.baseline.ok else 0.0)
+                # restore persisted calibration (deltas + pair-interaction
+                # corrections) from sibling entries under the same measurement
+                # conditions; this run's own observations below refine it
+                if store is not None:
+                    model.load_state(store.cost_model_for(mkey))
+                if report.baseline.ok:
+                    model.observe(Impl(), report.baseline.run_seconds)
+                for m in sorted((p for p in primed if p.ok and p.mapping()),
+                                key=lambda m: (len(m.mapping()), m.pattern)):
+                    model.observe(Impl(m.mapping()), m.run_seconds)
+                state.cost_model = model
 
-            # |non-ref genome space| of the survivors — make_strategy("auto")
-            # picks exhaustive/staged/surrogate from this.  A variant with
-            # a bound TuningSpace contributes every valid tile point (the
-            # bare default is one of them); without tuning each variant
-            # counts once, exactly as before.
-            space = 1
-            for r in eff_regions:
-                n = 0
-                for c in state.variants_of(r):
-                    n += (max(c.tuning.size(), 1)
-                          if c.tuning is not None else 1)
-                space *= 1 + n
-            report.search_space = max(space - 1, 0)
-            strategy = make_strategy(cfg, space_size=report.search_space)
-            strategy.run(state, ledger)
-            executor.shutdown()     # sync final cache stats before reading them
-            report.measurements = ledger.order       # budget-consuming, in order
-            report.reused = [m for m in ledger.reused() if m.mapping()]
-            report.quarantined = quarantine.blocked()
-            report.quarantine_records = quarantine.to_records()
-            report.strategy = strategy.name
-            report.search_trace = state.trace
-            report.skipped_combinations = state.skipped
-            # cost-model residual-bias notes (ROADMAP "region interaction
-            # terms"): pairs whose multi-gene observations stayed systematically
-            # biased are surfaced so the surrogate's trust in composite
-            # predictions is visible
-            report.cost_model_state = model.export_state()
-            bias = model.bias_notes()
-            if bias:
-                report.search_trace.append(
-                    {"stage": "cost-model pair bias", "pairs": bias})
-            # pipelined-verification wall-clock accounting
-            stats = executor.stats.as_dict()
-            report.search_trace.append({"stage": "verification executor",
-                                        **stats})
-            report.verify_workers = cfg.verify_workers
-            report.verify_wall_s = stats["verify_wall_s"]
-            report.compile_wall_s = stats["compile_wall_s"]
+                # |non-ref genome space| of the survivors — make_strategy("auto")
+                # picks exhaustive/staged/surrogate from this.  A variant with
+                # a bound TuningSpace contributes every valid tile point (the
+                # bare default is one of them); without tuning each variant
+                # counts once, exactly as before.
+                space = 1
+                for r in eff_regions:
+                    n = 0
+                    for c in state.variants_of(r):
+                        n += (max(c.tuning.size(), 1)
+                              if c.tuning is not None else 1)
+                    space *= 1 + n
+                report.search_space = max(space - 1, 0)
+                strategy = make_strategy(cfg, space_size=report.search_space)
+                strategy.run(state, ledger)
+                executor.shutdown()     # sync final cache stats before reading them
+                report.measurements = ledger.order       # budget-consuming, in order
+                report.reused = [m for m in ledger.reused() if m.mapping()]
+                report.quarantined = quarantine.blocked()
+                report.quarantine_records = quarantine.to_records()
+                report.strategy = strategy.name
+                report.search_trace = state.trace
+                report.skipped_combinations = state.skipped
+                # cost-model residual-bias notes (ROADMAP "region interaction
+                # terms"): pairs whose multi-gene observations stayed systematically
+                # biased are surfaced so the surrogate's trust in composite
+                # predictions is visible
+                report.cost_model_state = model.export_state()
+                bias = model.bias_notes()
+                if bias:
+                    report.search_trace.append(
+                        {"stage": "cost-model pair bias", "pairs": bias})
+                # pipelined-verification wall-clock accounting
+                stats = executor.stats.as_dict()
+                report.search_trace.append({"stage": "verification executor",
+                                            **stats})
+                report.verify_workers = cfg.verify_workers
+                report.verify_wall_s = stats["verify_wall_s"]
+                report.compile_wall_s = stats["compile_wall_s"]
 
             # ---- Step 5: select -------------------------------------------
-            # over everything the strategy was served this run: fresh
-            # measurements AND cross-run primed patterns it re-proposed
-            base_ok = report.baseline.ok
-            ok_measurements = [m for m in ledger.served
-                               if m.ok and m.mapping()]
-            best = min(ok_measurements, key=lambda m: m.run_seconds,
-                       default=None)
-            if best is not None and (not base_ok
-                                     or best.run_seconds < report.baseline.run_seconds):
-                report.best_pattern = best.mapping()
-                report.best_seconds = best.run_seconds
-                # a failed baseline gives no meaningful reference: still select
-                # the fastest working pattern, but never claim a speedup (and
-                # _sound() keeps this search out of the plan cache)
-                report.speedup = (report.baseline.run_seconds / best.run_seconds
-                                  if base_ok else 1.0)
-            else:
-                report.best_pattern = {}
-                report.best_seconds = (report.baseline.run_seconds
-                                       if base_ok else 0.0)
-                report.speedup = 1.0
-            return report
+            with obs.span("planner.select"):
+                # over everything the strategy was served this run: fresh
+                # measurements AND cross-run primed patterns it re-proposed
+                base_ok = report.baseline.ok
+                ok_measurements = [m for m in ledger.served
+                                   if m.ok and m.mapping()]
+                best = min(ok_measurements, key=lambda m: m.run_seconds,
+                           default=None)
+                if best is not None and (not base_ok
+                                         or best.run_seconds < report.baseline.run_seconds):
+                    report.best_pattern = best.mapping()
+                    report.best_seconds = best.run_seconds
+                    # a failed baseline gives no meaningful reference: still select
+                    # the fastest working pattern, but never claim a speedup (and
+                    # _sound() keeps this search out of the plan cache)
+                    report.speedup = (report.baseline.run_seconds / best.run_seconds
+                                      if base_ok else 1.0)
+                else:
+                    report.best_pattern = {}
+                    report.best_seconds = (report.baseline.run_seconds
+                                           if base_ok else 0.0)
+                    report.speedup = 1.0
+                return report
         finally:
             # shutdown is idempotent; the finally guards the pool and the
             # offloader-lifetime CompileCache against ANY exception from

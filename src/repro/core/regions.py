@@ -31,6 +31,8 @@ import itertools
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+import jax
+
 REGISTRY: dict[str, dict[str, Callable]] = {}
 
 # (region, variant) -> TuningSpace for variants that declared tile knobs
@@ -327,4 +329,7 @@ def dispatch(region: str, impl: Optional[Impl], *args, **kwargs):
             known = set(space.names())
             params = {k: v for k, v in params.items() if k in known}
         kwargs = {**kwargs, **params}
-    return table[choice](*args, **kwargs)
+    # names the region's operations in the lowered program's locations (and
+    # so in a device trace's op names); tracing-time only
+    with jax.named_scope(f"{region}:{choice}"):
+        return table[choice](*args, **kwargs)

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.fir import fir_vmem_bytes
 
 VMEM_BUDGET = 16 * 1024 * 1024      # 16 MiB per TPU core
@@ -92,12 +93,14 @@ class ResourceEstimate:
 
 def precompile(region: str, variant: str, fn: Callable, args,
                static_kwargs: Optional[dict] = None) -> ResourceEstimate:
-    """The cheap lowering pass.  ``args`` may be ShapeDtypeStructs."""
+    """The cheap lowering pass, under the span ``planner.lower`` (attribute
+    ``pattern``).  ``args`` may be ShapeDtypeStructs."""
     static_kwargs = static_kwargs or {}
     t0 = time.perf_counter()
     try:
-        lowered = jax.jit(lambda *a: fn(*a, **static_kwargs)).lower(*args)
-        text = lowered.as_text()
+        with obs.span("planner.lower", pattern=f"{region}={variant}"):
+            lowered = jax.jit(lambda *a: fn(*a, **static_kwargs)).lower(*args)
+            text = lowered.as_text()
         hlo_ops = sum(1 for line in text.splitlines() if "=" in line)
         est = _VMEM_ESTIMATORS.get((region, variant))
         vmem = float(est(*args)) if est else _default_vmem_estimate(

@@ -46,6 +46,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.regions import Impl, canonical_gene, gene_variant
 
 
@@ -141,12 +142,27 @@ def finish_compile(lowered, lower_seconds: float = 0.0,
             f"{type(e).__name__}: {e}")
 
 
-def aot_compile(fn, args) -> CompiledArtifact:
+def compile_lowered(lowered, lower_seconds: float = 0.0, error: str = "",
+                    pattern: str = "") -> CompiledArtifact:
+    """:func:`finish_compile` under the span ``planner.compile`` (attribute
+    ``pattern``); a pattern that failed to lower compiles nothing and opens
+    no span."""
+    if lowered is None:
+        return finish_compile(lowered, lower_seconds, error)
+    with obs.span("planner.compile", pattern=pattern):
+        return finish_compile(lowered, lower_seconds, error)
+
+
+def aot_compile(fn, args, pattern: str = "") -> CompiledArtifact:
     """AOT-compile ``fn`` for ``args`` (``jit -> lower -> compile``) and
-    time it.  Never raises: a failed lower/compile returns a non-``ok``
-    artifact that still accounts the seconds spent failing — compile cost
-    is the paper's central constraint even for rejected patterns."""
-    return finish_compile(*aot_lower(fn, args))
+    time it, the lowering under the span ``planner.lower`` and the compile
+    under ``planner.compile``.  Never raises: a failed lower/compile
+    returns a non-``ok`` artifact that still accounts the seconds spent
+    failing — compile cost is the paper's central constraint even for
+    rejected patterns."""
+    with obs.span("planner.lower", pattern=pattern):
+        lowered = aot_lower(fn, args)
+    return compile_lowered(*lowered, pattern=pattern)
 
 
 # ---------------------------------------------------------------------------
@@ -281,18 +297,21 @@ def time_callable(fn, args, *, warmup: int = 1, reps: int = 5,
       the threshold (real-hardware noise), re-measures up to ``remeasure``
       replacement reps, and reports the median of the kept reps;
       ``runs`` keeps every raw rep and ``outliers_rejected`` the count.
+
+    The runs (first run, warmup, reps) are the span ``planner.measure``
+    (attributes ``pattern`` and ``reps``).
     """
     impl = dict(impl) if impl is not None else None
     if precompiled is not None:
         art = precompiled
     elif compile_timeout_s and compile_timeout_s > 0:
-        ok, art, err = watchdog_call(aot_compile, (fn, args),
+        ok, art, err = watchdog_call(aot_compile, (fn, args, pattern),
                                      timeout_s=compile_timeout_s)
         if not ok:
             art = CompiledArtifact(None, compile_timeout_s,
                                    f"CompileTimeout: {err}")
     else:
-        art = aot_compile(fn, args)
+        art = aot_compile(fn, args, pattern)
     if not art.ok:
         return Measurement(pattern, art.compile_seconds, float("inf"), [],
                            False, art.error, impl=impl,
@@ -319,41 +338,42 @@ def time_callable(fn, args, *, warmup: int = 1, reps: int = 5,
                            failure_kind=classify_failure(error),
                            failure_phase="run")
 
-    try:
-        t0 = time.perf_counter()
-        out = run_once()
-        first_run_s = time.perf_counter() - t0
-        if check_finite and _nonfinite(out):
-            return run_failed("NonFiniteOutput: pattern produced NaN/Inf")
-        for _ in range(max(warmup - 1, 0)):
-            run_once()
-        runs = []
-        for _ in range(reps):
-            t = time.perf_counter()
-            run_once()
-            runs.append(time.perf_counter() - t)
-        rejected = 0
-        kept = runs
-        if outlier_mad and outlier_mad > 0 and len(runs) >= 3:
-            kept, rejected = _mad_reject(runs, outlier_mad)
-            # bounded re-measurement: replace (some of) the rejected reps,
-            # then re-filter the full raw set once — no open-ended loop
-            for _ in range(min(rejected, max(int(remeasure), 0))):
+    with obs.span("planner.measure", pattern=pattern, reps=reps):
+        try:
+            t0 = time.perf_counter()
+            out = run_once()
+            first_run_s = time.perf_counter() - t0
+            if check_finite and _nonfinite(out):
+                return run_failed("NonFiniteOutput: pattern produced NaN/Inf")
+            for _ in range(max(warmup - 1, 0)):
+                run_once()
+            runs = []
+            for _ in range(reps):
                 t = time.perf_counter()
                 run_once()
                 runs.append(time.perf_counter() - t)
-            if rejected:
-                refiltered, rejected = _mad_reject(runs, outlier_mad)
-                kept = refiltered if refiltered else kept
-        return Measurement(pattern, art.compile_seconds,
-                           float(np.median(kept)), runs, impl=impl,
-                           first_run_seconds=first_run_s,
-                           compile_wall_s=art.compile_seconds,
-                           outliers_rejected=rejected)
-    except _RunFailure as e:
-        return run_failed(str(e))
-    except Exception as e:  # noqa: BLE001 — a pattern failing = not a solution
-        return run_failed(f"{type(e).__name__}: {e}")
+            rejected = 0
+            kept = runs
+            if outlier_mad and outlier_mad > 0 and len(runs) >= 3:
+                kept, rejected = _mad_reject(runs, outlier_mad)
+                # bounded re-measurement: replace (some of) the rejected reps,
+                # then re-filter the full raw set once — no open-ended loop
+                for _ in range(min(rejected, max(int(remeasure), 0))):
+                    t = time.perf_counter()
+                    run_once()
+                    runs.append(time.perf_counter() - t)
+                if rejected:
+                    refiltered, rejected = _mad_reject(runs, outlier_mad)
+                    kept = refiltered if refiltered else kept
+            return Measurement(pattern, art.compile_seconds,
+                               float(np.median(kept)), runs, impl=impl,
+                               first_run_seconds=first_run_s,
+                               compile_wall_s=art.compile_seconds,
+                               outliers_rejected=rejected)
+        except _RunFailure as e:
+            return run_failed(str(e))
+        except Exception as e:  # noqa: BLE001 — a pattern failing = not a solution
+            return run_failed(f"{type(e).__name__}: {e}")
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.regions import Impl
 from repro.core.search import impl_key
@@ -338,7 +339,13 @@ class ServeEngine:
     def _install_pending(self) -> None:
         with self._plan_lock:
             prepared, self._pending_plan = self._pending_plan, None
-        if prepared is None or prepared.key == self._gen.key:
+        if prepared is None:
+            return
+        with obs.span("engine.install"):
+            self._install(prepared)
+
+    def _install(self, prepared: PlanGeneration) -> None:
+        if prepared.key == self._gen.key:
             return
         if prepared.key in self._faulted_keys:
             return                       # never re-install a plan that faulted
@@ -372,13 +379,19 @@ class ServeEngine:
         rollback to the last healthy generation and a retry of the same
         call, so the in-flight request never observes the fault.  Raises
         only when no rollback target remains (the all-ref plan itself is
-        faulting)."""
+        faulting).
+
+        The wait for the device (``engine.wait``) is timed apart from the
+        logits' copy to the host and their finite check (``engine.guard``)."""
         while True:
             gen = self._gen
             try:
                 out = getattr(gen, op)(*args)
-                logits = np.asarray(out[0])
-                if not np.all(np.isfinite(logits)):
+                with obs.span("engine.wait"):
+                    jax.block_until_ready(out[0])
+                with obs.span("engine.guard"):
+                    finite = np.all(np.isfinite(np.asarray(out[0])))
+                if not finite:
                     raise PlanFault(
                         f"{op} produced non-finite logits under plan "
                         f"{gen.impl.describe()!r}")
@@ -589,12 +602,16 @@ class ServeEngine:
                 batch[key] = fe
                 fe_sig = (key, tuple(fe.shape[1:]), str(fe.dtype))
             self._prefill_shapes.add((bucket, fe_sig))
-            logits, one_cache = self._plan_call("prefill", self.params, batch,
-                                                jnp.asarray(n, jnp.int32))
-            self.cache = cache_insert(self.cache, one_cache, slot)
-            first = int(self._sample_tokens(
-                logits[:, -1], [req.rid], [0],
-                [req.sampling.temperature], [req.sampling.top_k])[0])
+            with obs.span("engine.prefill", rid=req.rid, bucket=bucket,
+                          prompt_len=n):
+                logits, one_cache = self._plan_call(
+                    "prefill", self.params, batch, jnp.asarray(n, jnp.int32))
+            with obs.span("engine.insert"):
+                self.cache = cache_insert(self.cache, one_cache, slot)
+            with obs.span("engine.sample"):
+                first = int(self._sample_tokens(
+                    logits[:, -1], [req.rid], [0],
+                    [req.sampling.temperature], [req.sampling.top_k])[0])
             req.generated.append(first)
             req.admit_s = time.perf_counter()
             self.active[slot] = req
@@ -613,18 +630,20 @@ class ServeEngine:
         decoding = sum(r is not None for r in self.active)
         if not decoding:
             return 0
-        toks = jnp.asarray(self.last_tok[:, None])
-        pos = jnp.asarray(self.pos)
-        # commit the cache only AFTER the guard: a faulting plan's outputs
-        # (logits AND cache) are discarded whole, so a rollback retries the
-        # step from the exact pre-tick state
-        logits, new_cache = self._plan_call("decode", self.params, self.cache,
-                                            toks, pos)
-        self.cache = new_cache
-        steps = np.asarray([len(r.generated) if r is not None else 0
-                            for r in self.active], np.int32)
-        nxt = self._sample_tokens(logits[:, -1], self._rids, steps,
-                                  self._temps, self._top_ks)
+        with obs.span("engine.decode", slots=decoding):
+            toks = jnp.asarray(self.last_tok[:, None])
+            pos = jnp.asarray(self.pos)
+            # commit the cache only AFTER the guard: a faulting plan's
+            # outputs (logits AND cache) are discarded whole, so a rollback
+            # retries the step from the exact pre-tick state
+            logits, new_cache = self._plan_call("decode", self.params,
+                                                self.cache, toks, pos)
+            self.cache = new_cache
+            steps = np.asarray([len(r.generated) if r is not None else 0
+                                for r in self.active], np.int32)
+            with obs.span("engine.sample"):
+                nxt = self._sample_tokens(logits[:, -1], self._rids, steps,
+                                          self._temps, self._top_ks)
         for slot, req in enumerate(self.active):
             if req is None:
                 continue
@@ -640,8 +659,15 @@ class ServeEngine:
         strictly between ticks), admit, decode, record the tick event, then
         let an attached replanner evaluate its triggers."""
         self.ticks += 1
+        with obs.span("engine.tick", tick=self.ticks):
+            self._tick()
+
+    def _tick(self) -> None:
         self._install_pending()
-        admitted = self._admit()
+        admitted = []
+        if self.queue and any(r is None for r in self.active):
+            with obs.span("engine.admit"):
+                admitted = self._admit()
         decoded = self._tick_decode()
         # the serving generation survived a full tick: it is now a trusted
         # rollback target for future swaps
