@@ -154,7 +154,8 @@ def _rglru_vmem(a, b, h0, *_):
 
 @register_vmem_estimator("ssm_scan", "pallas")
 def _ssm_vmem(a, bx, c, h0, *_):
-    # state-major tiles [tc, N, bc]; the C column [tc, N, 1] is lane-padded
-    n = a.shape[-1]
+    # state-major tiles [tc, N, bc]; the C column [tc, N, 1] is lane-padded;
+    # the state of all D channels stays resident
+    d, n = a.shape[-2:]
     bc, tc = 128, 64
-    return 4.0 * (2 * tc * n * bc + tc * n * 128 + tc * bc + n * bc)
+    return 4.0 * (2 * tc * n * bc + tc * n * 128 + tc * bc + n * d)

@@ -31,8 +31,11 @@ from repro.models import params as P
 def default_impl(cfg: ModelConfig) -> Impl:
     """Architectural defaults (NOT planner decisions): big MoE configs must
     use the memory-lean expert-choice dispatch; SSM archs use the
-    time-sequential chunked scan (the Pallas kernel's schedule — §Perf
-    iteration A1 cut the falcon-mamba memory term 58x vs associative)."""
+    time-sequential scan (``seq``: one step after another — §Perf
+    iteration A1 cut the falcon-mamba memory term 58x vs associative).
+    The schedule is the same whichever form the scan's inputs take; given
+    ``mamba_block``'s factored form it runs as one Pallas kernel that
+    discretises each step in VMEM, given arrays as a chunked XLA scan."""
     imp = Impl()
     if cfg.is_moe:
         # group-local expert-choice is the production dispatch for ANY expert

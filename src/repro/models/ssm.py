@@ -11,6 +11,11 @@ S=4k, D=8k, N=16 would be >1 TB fp32 per pod) while keeping O(log chunk)
 sequential depth inside the chunk.  TPU adaptation note: on FPGA the paper's
 offload target is the loop nest; here the offload target is this scan region,
 and the Pallas kernel (`kernels/ssm_scan.py`) tiles channels into VMEM.
+
+``mamba_block`` hands the region the factored form (dt, x, A, B: see
+``kernels.ssm_scan.Factored``), never the [B, S, D, N] decay and input;
+each variant builds those where it needs them, the ``seq`` kernel one step
+at a time in VMEM.
 """
 from __future__ import annotations
 
@@ -18,6 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.regions import register_variant
+from repro.kernels.ssm_scan import Factored
+from repro.kernels.ssm_scan import ssm_scan as ssm_scan_kernel
+from repro.parallel import ctx as parallel_ctx
 
 
 # ---------------------------------------------------------------------------
@@ -51,6 +59,11 @@ def causal_depthwise_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = 
 # ---------------------------------------------------------------------------
 # Selective scan (region: "ssm_scan")
 # ---------------------------------------------------------------------------
+def _arrays(a, bx):
+    """The (a, bx) arrays of either input form of the region."""
+    return a.expand() if isinstance(a, Factored) else (a, bx)
+
+
 def _assoc_combine(l, r):
     a_l, b_l = l
     a_r, b_r = r
@@ -60,9 +73,12 @@ def _assoc_combine(l, r):
 @register_variant("ssm_scan", "ref")
 def ssm_scan_ref(a: jax.Array, bx: jax.Array, c: jax.Array, h0: jax.Array,
                  chunk: int = 256):
-    """a, bx: [B, S, D, N] (decay and input); c: [B, S, N]; h0: [B, D, N].
+    """a, bx: [B, S, D, N] (decay and input), or ``a`` a
+    :class:`~repro.kernels.ssm_scan.Factored` and ``bx`` None (expanded
+    here); c: [B, S, N]; h0: [B, D, N].
 
     Returns (y [B, S, D], h_final [B, D, N])."""
+    a, bx = _arrays(a, bx)
     b, s, d, n = a.shape
     chunk = min(chunk, s)
     pad = (-s) % chunk
@@ -95,20 +111,30 @@ def ssm_scan_ref(a: jax.Array, bx: jax.Array, c: jax.Array, h0: jax.Array,
 def ssm_scan_offload(a, bx, c, h0, chunk: int = 512):
     """Same math, larger chunks + fp32 state accumulation (the restructuring
     the Pallas kernel implements: fewer carries, MXU-aligned einsum)."""
+    a, bx = _arrays(a, bx)
     return ssm_scan_ref(a.astype(jnp.float32), bx.astype(jnp.float32),
                         c.astype(jnp.float32), h0, chunk=chunk)
 
 
 @register_variant("ssm_scan", "seq")
 def ssm_scan_seq_chunked(a, bx, c, h0, chunk: int = 256):
-    """Time-SEQUENTIAL chunked scan — the Pallas kernel's schedule in XLA.
+    """Time-SEQUENTIAL scan: one step after another, the state carried.
 
-    The associative-scan formulation streams O(S log chunk) bytes of
-    slice/concat intermediates per level; this variant reads each element
-    exactly once per pass (perf iteration 'falcon-mamba A1', EXPERIMENTS.md
-    §Perf).  Outer scan carries h across chunks (checkpointed, so backward
-    recomputes within-chunk states from the chunk-boundary h instead of
-    storing [B, S, D, N] residuals)."""
+    Where its inputs are built depends on the form it is given.  Given the
+    factored form (what ``mamba_block`` hands it), it runs the Pallas
+    kernel, which discretises each step in registers and keeps the state
+    in VMEM (:func:`_seq_factored`; under a mesh, whose partitioner cannot
+    split a kernel, it expands the form and takes the XLA path).  Given
+    arrays, it is a chunked XLA scan: the associative-scan formulation
+    streams O(S log chunk) bytes of slice/concat intermediates per level;
+    this reads each element exactly once per pass (perf iteration
+    'falcon-mamba A1', EXPERIMENTS.md §Perf).  Outer scan carries h across
+    chunks (checkpointed, so backward recomputes within-chunk states from
+    the chunk-boundary h instead of storing [B, S, D, N] residuals)."""
+    if isinstance(a, Factored):
+        if parallel_ctx.current() is None:
+            return _seq_factored(a, c, h0)
+        a, bx = a.expand()
     b, s, d, n = a.shape
     chunk = min(chunk, s)
     pad = (-s) % chunk
@@ -142,6 +168,36 @@ def ssm_scan_seq_chunked(a, bx, c, h0, chunk: int = 256):
     return y, h_f
 
 
+# (block_c, time_chunk) of the seq variant's kernel, the fastest of a sweep
+# at buckets 128 and 512 on a TPU v5e (PERF.md, section 5)
+SEQ_TILES = (1024, 128)
+
+
+@jax.custom_vjp
+def _seq_factored(f: Factored, c, h0):
+    """The seq schedule as one Pallas kernel over the factored form: the
+    [B, S, D, N] decay and input never reach HBM.  Differentiated through
+    the chunked XLA scan on the expanded form (recomputed in backward)."""
+    block_c, time_chunk = SEQ_TILES
+    d = f.dt.shape[-1]
+    return ssm_scan_kernel(f, None, c, h0, time_chunk=time_chunk,
+                           block_c=block_c if d % block_c == 0 else d)
+
+
+def _seq_factored_fwd(f, c, h0):
+    return _seq_factored(f, c, h0), (f, c, h0)
+
+
+def _seq_factored_bwd(res, g):
+    def xla(f, c, h0):
+        y, h_f = ssm_scan_seq_chunked(*f.expand(), c, h0)
+        return y.astype(g[0].dtype), h_f.astype(g[1].dtype)
+    return jax.vjp(xla, *res)[1](g)
+
+
+_seq_factored.defvjp(_seq_factored_fwd, _seq_factored_bwd)
+
+
 def ssm_decode_step(a, bx, c, h):
     """Single-token recurrence.  a, bx: [B, D, N]; c: [B, N]; h: [B, D, N]."""
     h_new = a * h + bx
@@ -155,9 +211,9 @@ def ssm_decode_step(a, bx, c, h):
 def mamba_block(params, x, *, cfg, impl=None, state=None, length=None):
     """x: [B, S, D_model].  state: None (train) or dict(conv, h) for decode-
     style stateful prefill.  ``length`` (traced scalar): positions >= length
-    are right-padding — their recurrence steps are masked to the identity
-    (a=1, bx=0) so the final state is exactly the state after ``length`` real
-    tokens (bucketed prefill).  Returns (y, new_state)."""
+    are right-padding — their dt is zeroed, which makes their recurrence
+    steps the identity (a=1, bx=0), so the final state is exactly the state
+    after ``length`` real tokens (bucketed prefill).  Returns (y, new_state)."""
     from repro.core.regions import dispatch
 
     b, s, _ = x.shape
@@ -176,19 +232,18 @@ def mamba_block(params, x, *, cfg, impl=None, state=None, length=None):
     dt = jax.nn.softplus(dt @ params["w_dt"] + params["dt_bias"])   # [B, S, Di]
     a_log = -jnp.exp(params["a_log"].astype(jnp.float32))      # [Di, N]
 
-    a = jnp.exp(dt[..., None].astype(jnp.float32) * a_log)     # [B, S, Di, N]
-    bx = (dt * xi)[..., None] * bmat[:, :, None, :]            # [B, S, Di, N]
     if length is not None:
-        pad = (jnp.arange(s) >= length)[None, :, None, None]
-        a = jnp.where(pad, 1.0, a)
-        bx = jnp.where(pad, 0.0, bx)
+        # dt = 0 makes a padded step the identity: a = exp(0) = 1, bx = 0
+        dt = jnp.where((jnp.arange(s) < length)[None, :, None], dt, 0)
     from repro.parallel.ctx import constrain
-    a = constrain(a, ("batch", None, "inner", None))
-    bx = constrain(bx, ("batch", None, "inner", None))
+    inner = ("batch", None, "inner")
+    # the factored form: each variant builds a = exp(dt * A) and
+    # bx = dt * x * B [B, S, Di, N] where it needs them
+    factors = Factored(constrain(dt, inner), constrain(xi, inner), a_log, bmat)
     h0 = (jnp.zeros((b, di, n), jnp.float32) if state is None
           else state["h"].astype(jnp.float32))
-    y, h_f = dispatch("ssm_scan", impl, a.astype(x.dtype), bx.astype(x.dtype),
-                      cmat.astype(x.dtype), h0)
+    y, h_f = dispatch("ssm_scan", impl, factors, None, cmat.astype(x.dtype),
+                      h0)
     y = y + xi * params["d_skip"]
     y = y * jax.nn.silu(z)
     out = y @ params["w_out"]

@@ -11,7 +11,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mriq import mriq_compute_q
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rmsnorm import rmsnorm
-from repro.kernels.ssm_scan import ssm_scan
+from repro.kernels.ssm_scan import Factored, ssm_scan
 
 KEY = jax.random.PRNGKey(0)
 
@@ -151,6 +151,100 @@ def test_ssm_kernel_matches_seq(b, s, d, n, bc, tc):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(hf_ref),
                                rtol=1e-4, atol=1e-4)
+
+
+def _factored(b, s, d, n, length=None, dtype=jnp.float32):
+    """Random factored scan inputs; dt zeroed at positions >= length."""
+    k = jax.random.split(KEY, 6)
+    dt = jax.nn.softplus(jax.random.normal(k[0], (b, s, d))) * 0.1
+    if length is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < length, dt, 0)
+    f = Factored(dt.astype(dtype), jax.random.normal(k[1], (b, s, d), dtype),
+                 -jnp.exp(0.5 * jax.random.normal(k[2], (d, n))),
+                 jax.random.normal(k[3], (b, s, n), dtype))
+    c = jax.random.normal(k[4], (b, s, n), dtype)
+    h0 = jax.random.normal(k[5], (b, d, n), jnp.float32)
+    return f, c, h0
+
+
+# block_c=128 splits d=256 in two; time_chunk 48 divides none of the S, so
+# the kernel pads S with identity steps
+@pytest.mark.parametrize("bc,tc", [(128, 32), (256, 48)])
+@pytest.mark.parametrize("d", [64, 256])
+@pytest.mark.parametrize("b,s", [(2, 16), (1, 64), (1, 128)])
+def test_ssm_kernel_factored_matches_seq(b, s, d, bc, tc):
+    """The kernel that discretises inside the scan, against the sequential
+    oracle on the expanded inputs; dt is zeroed past ``length``, so the
+    final state is the state after ``length`` real steps."""
+    n, length = 16, s - s // 4 + 1
+    f, c, h0 = _factored(b, s, d, n, length)
+    y, hf = ssm_scan(f, None, c, h0, block_c=bc, time_chunk=tc,
+                     interpret=True)
+    a, bx = f.expand()
+    y_ref, hf_ref = R.ssm_scan_seq(a, bx, c, h0)
+    assert y.shape == (b, s, d) and hf.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+    _, hf_len = R.ssm_scan_seq(a[:, :length], bx[:, :length],
+                               c[:, :length], h0)
+    np.testing.assert_allclose(np.asarray(hf), np.asarray(hf_len),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("variant", ["ref", "offload", "seq", "pallas"])
+def test_ssm_scan_variants_agree_on_the_factored_form(variant):
+    import repro.kernels.ops  # noqa: F401 — registers the pallas variant
+    import repro.models.ssm  # noqa: F401 — registers ref, offload, seq
+    from repro.core.regions import variants
+
+    f, c, h0 = _factored(2, 40, 128, 8, length=33)
+    y, hf = variants("ssm_scan")[variant](f, None, c, h0)
+    y_ref, hf_ref = R.ssm_scan_seq(*f.expand(), c, h0)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(hf), np.asarray(hf_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_seq_runs_the_kernel_only_outside_a_mesh():
+    """A mesh's partitioner cannot split the kernel: under a parallel
+    context ``seq`` expands the factored form and scans in XLA."""
+    from jax.sharding import Mesh
+
+    from repro.models.ssm import ssm_scan_seq_chunked
+    from repro.parallel.ctx import parallel_context
+    from repro.parallel.rules import ParallelismConfig
+
+    f, c, h0 = _factored(1, 16, 128, 8)
+
+    def prims():
+        jaxpr = jax.make_jaxpr(
+            lambda f, c, h0: ssm_scan_seq_chunked(f, None, c, h0))(f, c, h0)
+        return str(jaxpr)
+
+    assert "pallas_call" in prims()
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    with mesh, parallel_context(mesh, ParallelismConfig()):
+        assert "pallas_call" not in prims()
+
+
+def test_seq_factored_gradient_matches_ref():
+    """seq's kernel path is differentiated through the XLA scan: its
+    gradients equal the associative ref's on the same factored inputs."""
+    from repro.models.ssm import ssm_scan_ref, ssm_scan_seq_chunked
+
+    f, c, h0 = _factored(1, 24, 128, 8)
+
+    def loss(scan):
+        def fn(f, c, h0):
+            y, hf = scan(f, None, c, h0)
+            return jnp.sum(y * y) + jnp.sum(hf)
+        return jax.grad(fn, argnums=(0, 1, 2))(f, c, h0)
+
+    for got, want in zip(jax.tree.leaves(loss(ssm_scan_seq_chunked)),
+                         jax.tree.leaves(loss(ssm_scan_ref))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-3, atol=1e-3)
 
 
 # chunked associative-scan refs (model path) vs sequential oracle

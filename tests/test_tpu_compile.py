@@ -9,6 +9,7 @@ import, so that every test worker collects the same tests and only the
 worker running this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ from repro.kernels.fir import fir_filter_bank
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mriq import mriq_compute_q
 from repro.kernels.rglru_scan import rglru_scan
-from repro.kernels.ssm_scan import ssm_scan
+from repro.kernels.ssm_scan import Factored, ssm_scan
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +80,60 @@ def test_ssm_scan_compiles_at_falcon_mamba_widths(one_chip, dtype):
     _compile(lambda a, bx, c, h0: ssm_scan(a, bx, c, h0, interpret=False),
              [((1, s, d, n), dtype), ((1, s, d, n), dtype),
               ((1, s, n), dtype), ((1, d, n), jnp.float32)], one_chip)
+
+
+def test_fused_ssm_scan_compiles_at_falcon_mamba_widths(one_chip):
+    from repro.models.ssm import SEQ_TILES
+    cfg = get_config("falcon-mamba-7b")
+    d, n, s = cfg.d_inner, cfg.ssm_state, 512
+    bc, tc = SEQ_TILES
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    _compile(lambda dt, x, a, b, c, h0: ssm_scan(
+                 Factored(dt, x, a, b), None, c, h0, block_c=bc,
+                 time_chunk=tc, interpret=False),
+             [((1, s, d), bf16), ((1, s, d), bf16), ((d, n), f32),
+              ((1, s, n), bf16), ((1, s, n), bf16), ((1, d, n), f32)],
+             one_chip)
+
+
+def _prefill_hlo(impl, s, sharding):
+    """Optimised HLO of the reduced falcon-mamba bucketed prefill (S=s)
+    under ``impl``, compiled for the described chip."""
+    from repro.core.regions import Impl
+    from repro.models import factory as F
+
+    cfg = get_config("falcon-mamba-7b").reduced()
+    params = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding),
+        jax.eval_shape(lambda: F.init_params(cfg, jax.random.PRNGKey(0))))
+    step = F.make_bucketed_prefill_step(cfg, impl=Impl(impl), ctx=2 * s)
+    args = ({"tokens": jax.ShapeDtypeStruct((1, s), jnp.int32,
+                                            sharding=sharding)},
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding))
+    text = jax.jit(step).lower(params, *args).compile().as_text()
+    return cfg, text
+
+
+def test_bucketed_prefill_never_builds_the_expanded_scan_inputs(
+        one_chip, monkeypatch):
+    """Under ``ssm_scan=seq`` the prefill holds the Mosaic kernel and no
+    [1, S, d_inner, N] value; under ``ref`` (expanded by XLA) it holds
+    them, so the pattern can see them."""
+    import repro.kernels.ssm_scan as K
+
+    # the chip's branch: this process's backend is the CPU
+    monkeypatch.setattr(K, "resolve_interpret", lambda interpret: False)
+    s = 40
+    K.ssm_scan.clear_cache()
+    try:
+        cfg, seq = _prefill_hlo({"ssm_scan": "seq"}, s, one_chip)
+    finally:
+        K.ssm_scan.clear_cache()
+    _, ref = _prefill_hlo({"ssm_scan": "ref"}, s, one_chip)
+    expanded = re.compile(rf"\[1,{s},{cfg.d_inner},{cfg.ssm_state}\]")
+    assert "tpu_custom_call" in seq
+    assert not expanded.search(seq)
+    assert expanded.search(ref)
 
 
 def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip):
