@@ -20,6 +20,10 @@ LOCAL_ATTN = "local"     # sliding-window attention block
 RGLRU = "rglru"          # RG-LRU recurrent block (recurrentgemma)
 SSM = "ssm"              # Mamba-1 selective-state-space block
 
+# Feed-forward kinds of a layer (``ModelConfig.ffn_kinds``).
+MLP = "mlp"              # dense SwiGLU (GELU for audio) MLP
+MOE = "moe"              # routed experts
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -42,12 +46,26 @@ class ModelConfig:
     moe_d_ff: int = 0                # expert FFN width (if != d_ff)
     dense_residual_d_ff: int = 0     # arctic: parallel dense FFN next to MoE
     capacity_factor: float = 1.25
+    # layer i has experts iff i % moe_layer_period == moe_layer_offset (jamba);
+    # 0: the experts sit on the attention layers and every other layer has a
+    # dense MLP
+    moe_layer_period: int = 0
+    moe_layer_offset: int = 0
+    moe_dropless: bool = False       # dropless token-choice top-k (region
+                                     # moe_dropless) in place of moe_ffn
+    moe_renormalize: bool = True     # top-k gate weights rescaled to sum 1
+    experts_first: int = 0           # first of the experts held here
+    experts_held: int = 0            # experts held here (0 -> num_experts);
+                                     # the router still spans num_experts
 
     # --- SSM (mamba-1) ---
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
     dt_rank: int = 0                 # 0 -> ceil(d_model / 16)
+    ssm_conv_bias: bool = False      # the depthwise conv adds a bias
+    ssm_dt_norm: bool = False        # dt, B and C RMS-normalised (learned
+                                     # weights, norm_eps) before dt_proj / scan
 
     # --- hybrid (recurrentgemma) ---
     layer_pattern: Sequence[str] = ()   # repeating block pattern, e.g. (RGLRU, RGLRU, LOCAL_ATTN)
@@ -70,6 +88,7 @@ class ModelConfig:
     # --- misc knobs ---
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    use_rope: bool = True            # False: attention sees no positions
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
@@ -104,6 +123,10 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def sub_quadratic(self) -> bool:
         """True if the arch can decode at 500k context without a full-size
         dense KV cache (SSM state / bounded local window)."""
@@ -127,13 +150,26 @@ class ModelConfig:
             return [pat[i % len(pat)] for i in range(self.num_layers)]
         return [ATTN] * self.num_layers
 
+    def ffn_kinds(self) -> list[str]:
+        """Per-layer feed-forward kind: ``MOE``, ``MLP`` or ``""`` (none)."""
+        out = []
+        for i, kind in enumerate(self.layer_kinds()):
+            if not self.d_ff:
+                out.append("")
+            elif self.moe_layer_period:
+                out.append(MOE if i % self.moe_layer_period
+                           == self.moe_layer_offset else MLP)
+            else:
+                out.append(MOE if self.is_moe and kind in (ATTN, LOCAL_ATTN)
+                           else MLP)
+        return out
+
     # ------------------------------------------------------------------
     def param_count(self) -> int:
         """Analytic parameter count (used for 6ND model-FLOPs)."""
         hd = self.resolved_head_dim
         emb = self.vocab_size * self.d_model
         out = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        per_layer = 0
         counts = {k: 0 for k in (ATTN, LOCAL_ATTN, RGLRU, SSM)}
         for k in self.layer_kinds():
             counts[k] += 1
@@ -144,19 +180,18 @@ class ModelConfig:
                   + self.num_heads * hd * self.d_model)        # Wo
         if self.qkv_bias:
             attn_p += (self.num_heads + 2 * self.num_kv_heads) * hd
-        # FFN (SwiGLU: 3 mats)
-        if self.is_moe:
-            eff = self.moe_d_ff or self.d_ff
-            ffn_p = self.num_experts * 3 * self.d_model * eff
-            ffn_p += self.d_model * self.num_experts            # router
-            if self.dense_residual_d_ff:
-                ffn_p += 3 * self.d_model * self.dense_residual_d_ff
-        else:
-            ffn_p = 3 * self.d_model * self.d_ff
+        # FFN (SwiGLU: 3 mats) by each layer's kind; experts as held here
+        eff = self.moe_d_ff or self.d_ff
+        moe_p = (self.resolved_experts_held * 3 * self.d_model * eff
+                 + self.d_model * self.num_experts)            # router
+        if self.dense_residual_d_ff:
+            moe_p += 3 * self.d_model * self.dense_residual_d_ff
+        mlp_p = 3 * self.d_model * self.d_ff
+        ffn_p = sum(moe_p if f == MOE else mlp_p if f == MLP else 0
+                    for f in self.ffn_kinds())
         norm_p = 2 * self.d_model
-        per_layer = ffn_p + norm_p
         total = emb + out + self.d_model  # final norm
-        total += n_attn * attn_p + self.num_layers * per_layer
+        total += n_attn * attn_p + self.num_layers * norm_p + ffn_p
         # recurrent blocks
         if counts[RGLRU]:
             d_rnn = self.rglru_d_rnn or self.d_model
@@ -173,6 +208,10 @@ class ModelConfig:
                      + di * st                       # A_log
                      + di                            # D
                      + di * self.d_model)            # out_proj
+            if self.ssm_conv_bias:
+                ssm_p += di
+            if self.ssm_dt_norm:
+                ssm_p += dtr + 2 * st                # dt, B, C norm weights
             total += counts[SSM] * ssm_p
         # enc-dec extras
         if self.encoder_layers:
@@ -187,18 +226,30 @@ class ModelConfig:
         return int(total)
 
     def active_param_count(self) -> int:
-        """Activated parameters per token (MoE: only routed experts count)."""
+        """Activated parameters per token (MoE: only routed experts count).
+        Where only some experts are held here, a token's picks land on them
+        at their share: ``experts_per_token * held / num_experts`` experts
+        per expert layer, in expectation."""
         if not self.is_moe:
             return self.param_count()
         eff = self.moe_d_ff or self.d_ff
-        all_experts = self.num_layers * self.num_experts * 3 * self.d_model * eff
-        active = self.num_layers * self.experts_per_token * 3 * self.d_model * eff
-        return int(self.param_count() - all_experts + active)
+        held = self.resolved_experts_held
+        n_moe = self.ffn_kinds().count(MOE)
+        per_expert = 3 * self.d_model * eff
+        active = (n_moe * per_expert * self.experts_per_token * held
+                  // self.num_experts)
+        return int(self.param_count() - n_moe * held * per_expert + active)
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         pat = tuple(self.layer_pattern[:3]) if self.layer_pattern else ()
+        if self.moe_layer_period:
+            # experts by layer position: one whole period of the pattern
+            pat = tuple(self.layer_pattern)
+        experts = min(self.num_experts, 4)
+        held = (self.experts_held * experts // self.num_experts
+                if self.experts_held else 0)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
@@ -212,7 +263,9 @@ class ModelConfig:
             # trace against the full config's annotation
             d_ff=min(self.d_ff, 128),
             vocab_size=256,
-            num_experts=min(self.num_experts, 4),
+            num_experts=experts,
+            experts_held=held,
+            experts_first=self.experts_first * experts // max(self.num_experts, 1),
             experts_per_token=min(self.experts_per_token, 2),
             moe_d_ff=64 if self.moe_d_ff else 0,
             dense_residual_d_ff=64 if self.dense_residual_d_ff else 0,
@@ -281,6 +334,7 @@ ARCH_IDS = (
 # EXPERIMENTS.md stays exact); loaded into the registry all the same.
 BONUS_ARCH_IDS = (
     "mixtral-8x7b",
+    "jamba2-mini",
 )
 
 _REGISTRY: dict[str, ModelConfig] = {}

@@ -114,6 +114,12 @@ def _split_heads(x, n_heads, hd):
     return x.reshape(b, s, n_heads, hd).transpose(0, 2, 1, 3)   # [B, H, S, hd]
 
 
+def _rope(x, positions, cfg: ModelConfig):
+    """Rotary embedding at ``positions``, or ``x`` unchanged where the
+    architecture gives attention no positions (``cfg.use_rope`` off)."""
+    return L.apply_rope(x, positions, cfg.rope_theta) if cfg.use_rope else x
+
+
 def _merge_heads(x):
     b, h, s, hd = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
@@ -141,8 +147,8 @@ def attn_apply(p, x, *, cfg: ModelConfig, positions, impl=None, causal=True,
     src = h if kv_src is None else kv_src
     q, k, v = _qkv(p, h, src, cfg)
     kpos = positions if kv_positions is None else kv_positions
-    q = L.apply_rope(q, positions[:, None, :], cfg.rope_theta)
-    k = L.apply_rope(k, kpos[:, None, :], cfg.rope_theta)
+    q = _rope(q, positions[:, None, :], cfg)
+    k = _rope(k, kpos[:, None, :], cfg)
     # Query heads shard over 'model' when divisible (qwen2 64H); otherwise
     # fall back to sequence-parallel queries (phi3 40H / arctic 56H /
     # whisper 12H on a 16-way axis would otherwise replicate the S^2 work on
@@ -184,14 +190,14 @@ def attn_decode(p, x, cache, *, cfg: ModelConfig, pos, impl=None, window=0,
         hd = cfg.resolved_head_dim
         q = _split_heads(h @ p["wq"] + (p["bq"] if "bq" in p else 0.0),
                          cfg.num_heads, hd)
-        q = L.apply_rope(q, pos[:, None, None], cfg.rope_theta)
+        q = _rope(q, pos[:, None, None], cfg)
         out = L.decode_attention(q, k_cache, v_cache, slot_pos,
                                  jnp.full_like(pos, 2**30), window=0)
         out = _merge_heads(out) @ p["wo"]
         return x + out.astype(x.dtype), cache
     q, k_new, v_new = _qkv(p, h, h, cfg)
-    q = L.apply_rope(q, pos[:, None, None], cfg.rope_theta)
-    k_new = L.apply_rope(k_new, pos[:, None, None], cfg.rope_theta)
+    q = _rope(q, pos[:, None, None], cfg)
+    k_new = _rope(k_new, pos[:, None, None], cfg)
     k_c, v_c, sp = L.cache_update(cache["k"], cache["v"], cache["slot_pos"],
                                   k_new, v_new, pos, window=window)
     out = L.decode_attention(q, k_c, v_c, sp, pos, window=window)
@@ -211,7 +217,7 @@ def attn_prefill_cache(p, x, *, cfg: ModelConfig, positions, window=0, ctx=None,
     decode_attention masks them."""
     h = L.rms_norm(x, p["ln"], cfg.norm_eps)
     _, k, v = _qkv(p, h, h, cfg)
-    k = L.apply_rope(k, positions[:, None, :], cfg.rope_theta)
+    k = _rope(k, positions[:, None, :], cfg)
     b, hkv, s, hd = k.shape
     size = min(ctx or s, window) if window else (ctx or s)
     if length is not None:
@@ -279,14 +285,16 @@ def mlp_apply(p, x, *, cfg: ModelConfig, impl=None):
 # MoE block
 # ---------------------------------------------------------------------------
 def moe_template(cfg: ModelConfig) -> dict:
-    d, e = cfg.d_model, cfg.num_experts
+    """The router spans all ``num_experts``; the expert stacks hold only the
+    experts held here (``resolved_experts_held``)."""
+    d, e, held = cfg.d_model, cfg.num_experts, cfg.resolved_experts_held
     f = cfg.moe_d_ff or cfg.d_ff
     t = {
         "ln": spec([d], ("embed",), "zeros"),
         "router": spec([d, e], ("embed", "experts")),
-        "w_gate": spec([e, d, f], ("experts", "embed", "expert_mlp")),
-        "w_up": spec([e, d, f], ("experts", "embed", "expert_mlp")),
-        "w_down": spec([e, f, d], ("experts", "expert_mlp", "embed"), "scaled"),
+        "w_gate": spec([held, d, f], ("experts", "embed", "expert_mlp")),
+        "w_up": spec([held, d, f], ("experts", "embed", "expert_mlp")),
+        "w_down": spec([held, f, d], ("experts", "expert_mlp", "embed"), "scaled"),
     }
     if cfg.dense_residual_d_ff:
         t["dense"] = {k: v for k, v in
@@ -295,19 +303,36 @@ def moe_template(cfg: ModelConfig) -> dict:
     return t
 
 
-def moe_apply(p, x, *, cfg: ModelConfig, impl=None):
+def moe_apply(p, x, *, cfg: ModelConfig, impl=None, length=None):
+    """Returns (x + the experts' output, counts): ``counts`` int32 [2] are
+    the (token, expert) pairs routed to the experts held here and the held
+    experts they reached, under ``cfg.moe_dropless`` (else None).
+    ``length`` (traced scalar, dropless only): positions >= length are
+    bucket padding, routed to no expert."""
     b, s, d = x.shape
     h = L.rms_norm(x, p["ln"], cfg.norm_eps)
     flat = h.reshape(b * s, d)
-    moe_out = dispatch("moe_ffn", impl, flat,
-                       {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")},
-                       num_experts=cfg.num_experts, k=cfg.experts_per_token,
-                       capacity_factor=cfg.capacity_factor, inner_impl=impl)
+    experts = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
+    counts = None
+    if cfg.moe_dropless:
+        valid = None
+        if length is not None:
+            valid = jnp.broadcast_to(jnp.arange(s) < length, (b, s)).reshape(-1)
+        moe_out, counts = dispatch(
+            "moe_dropless", impl, flat, experts, k=cfg.experts_per_token,
+            first=cfg.experts_first, renormalize=cfg.moe_renormalize,
+            valid=valid)
+    else:
+        moe_out = dispatch("moe_ffn", impl, flat, experts,
+                           num_experts=cfg.num_experts,
+                           k=cfg.experts_per_token,
+                           capacity_factor=cfg.capacity_factor,
+                           inner_impl=impl)
     out = moe_out.reshape(b, s, d)
     if "dense" in p:
         dp = p["dense"]
         out = out + L.swiglu(h, dp["w_gate"], dp["w_up"], dp["w_down"]).astype(x.dtype)
-    return x + out.astype(x.dtype)
+    return x + out.astype(x.dtype), counts
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +341,7 @@ def moe_apply(p, x, *, cfg: ModelConfig, impl=None):
 def ssm_template(cfg: ModelConfig) -> dict:
     d, di, n = cfg.d_model, cfg.d_inner, cfg.ssm_state
     dtr, k = cfg.resolved_dt_rank, cfg.ssm_conv
-    return {
+    t = {
         "ln": spec([d], ("embed",), "zeros"),
         "w_in": spec([d, 2 * di], ("embed", "inner2")),
         "conv_w": spec([k, di], (None, "inner"), "normal", scale=0.3),
@@ -327,6 +352,13 @@ def ssm_template(cfg: ModelConfig) -> dict:
         "d_skip": spec([di], ("inner",), "ones"),
         "w_out": spec([di, d], ("inner", "embed"), "scaled"),
     }
+    if cfg.ssm_conv_bias:
+        t["conv_b"] = spec([di], ("inner",), "zeros")
+    if cfg.ssm_dt_norm:
+        t["dt_ln"] = spec([dtr], (None,), "zeros")
+        t["b_ln"] = spec([n], (None,), "zeros")
+        t["c_ln"] = spec([n], (None,), "zeros")
+    return t
 
 
 def ssm_cache_template(cfg: ModelConfig, batch: int) -> dict:

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import SSM, ModelConfig, ShapeConfig
 from repro.core.regions import Impl
 from repro.models import lm
 from repro.models import params as P
@@ -30,21 +30,22 @@ from repro.models import params as P
 # ---------------------------------------------------------------------------
 def default_impl(cfg: ModelConfig) -> Impl:
     """Architectural defaults (NOT planner decisions): big MoE configs must
-    use the memory-lean expert-choice dispatch; SSM archs use the
-    time-sequential scan (``seq``: one step after another — §Perf
+    use the memory-lean expert-choice dispatch (a dropless config's region
+    keeps its plain ``ref``: the served plan names its variant); any stack
+    with Mamba layers uses the time-sequential scan (``seq``: one step after another — §Perf
     iteration A1 cut the falcon-mamba memory term 58x vs associative).
     The schedule is the same whichever form the scan's inputs take; given
     ``mamba_block``'s factored form it runs as one Pallas kernel that
     discretises each step in VMEM, given arrays as a chunked XLA scan."""
     imp = Impl()
-    if cfg.is_moe:
+    if cfg.is_moe and not cfg.moe_dropless:
         # group-local expert-choice is the production dispatch for ANY expert
         # count: the token-choice one-hot path materializes a [T, E, C]
         # tensor that scales with the global token count (measured: 22 TB
         # per chip on the mixtral train cell) and exists for small-scale
         # semantic tests only (select explicitly via Impl({'moe_ffn':'ref'})).
         imp["moe_ffn"] = "offload"
-    if cfg.family == "ssm":
+    if SSM in cfg.layer_kinds():
         imp["ssm_scan"] = "seq"
     return imp
 
@@ -188,26 +189,31 @@ def prefill_bucket(n: int, max_len: int, min_bucket: int = PREFILL_BUCKET_MIN) -
 
 
 def make_bucketed_prefill_step(cfg: ModelConfig, impl: Optional[Impl] = None,
-                               ctx: Optional[int] = None):
+                               ctx: Optional[int] = None, counts: bool = False):
     """Prefill step over right-padded prompts: ``(params, batch, length)``
     where batch['tokens'] is [B, bucket] and ``length`` is the traced scalar
     count of real tokens.  Position/length masking inside ``lm.prefill``
     makes logits and caches exact for the real tokens, so the engine
-    compiles once per bucket instead of once per distinct prompt length."""
+    compiles once per bucket instead of once per distinct prompt length.
+    With ``counts`` the step also returns the routing counters
+    (``lm.prefill``)."""
     impl = impl if impl is not None else default_impl(cfg)
 
     def prefill_step(params, batch, length):
         fe = batch.get("patches", batch.get("frames"))
         return lm.prefill(params, batch["tokens"], cfg=cfg, impl=impl,
-                          frontend_emb=fe, ctx=ctx, length=length)
+                          frontend_emb=fe, ctx=ctx, length=length,
+                          counts=counts)
     return prefill_step
 
 
-def make_serve_step(cfg: ModelConfig, impl: Optional[Impl] = None):
+def make_serve_step(cfg: ModelConfig, impl: Optional[Impl] = None,
+                    counts: bool = False):
     impl = impl if impl is not None else default_impl(cfg)
 
     def serve_step(params, cache, tokens, pos):
-        return lm.decode_step(params, cache, tokens, pos, cfg=cfg, impl=impl)
+        return lm.decode_step(params, cache, tokens, pos, cfg=cfg, impl=impl,
+                              counts=counts)
     return serve_step
 
 

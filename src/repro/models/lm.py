@@ -8,6 +8,9 @@ config.
 Hybrid archs (recurrentgemma) repeat a block *pattern*; we scan over whole
 pattern repetitions ("units") and apply the non-multiple tail unstacked:
 26 layers of (RGLRU, RGLRU, LOCAL) = scan over 8 units + 2-layer tail.
+A layer's feed-forward kind (``cfg.ffn_kinds``) follows its position, so
+it repeats with the unit too (jamba: experts on the odd layers of each
+8-layer period, whatever their mixer).
 """
 from __future__ import annotations
 
@@ -18,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, LOCAL_ATTN, RGLRU, SSM, ModelConfig
+from repro.configs.base import ATTN, LOCAL_ATTN, MLP, MOE, RGLRU, SSM, ModelConfig
 from repro.models import blocks as B
 from repro.models import layers as L
 from repro.models.params import spec, stack_tree
@@ -37,7 +40,21 @@ def layer_plan(cfg: ModelConfig) -> tuple[tuple[str, ...], int, tuple[str, ...]]
     return (kinds[0],), len(kinds), ()
 
 
-def layer_template(cfg: ModelConfig, kind: str) -> dict:
+def layer_ffns(cfg: ModelConfig) -> tuple[tuple[str, ...], tuple[str, ...]]:
+    """Feed-forward kinds of the unit's layers and of the tail's, laid out
+    as :func:`layer_plan` lays out the mixers; every unit must repeat the
+    first one's."""
+    unit_kinds, reps, tail_kinds = layer_plan(cfg)
+    ffns = cfg.ffn_kinds()
+    m = len(unit_kinds)
+    unit = tuple(ffns[:m])
+    if any(tuple(ffns[r * m:(r + 1) * m]) != unit for r in range(reps)):
+        raise ValueError(f"{cfg.name}: the feed-forward kinds do not repeat "
+                         f"with the {m}-layer unit")
+    return unit, tuple(ffns[reps * m:])
+
+
+def layer_template(cfg: ModelConfig, kind: str, ffn: str) -> dict:
     t: dict = {}
     if kind in (ATTN, LOCAL_ATTN):
         t["attn"] = B.attn_template(cfg)
@@ -49,11 +66,10 @@ def layer_template(cfg: ModelConfig, kind: str) -> dict:
         t["ssm"] = B.ssm_template(cfg)
     else:
         raise ValueError(kind)
-    if cfg.d_ff:
-        if cfg.is_moe and kind in (ATTN, LOCAL_ATTN):
-            t["ffn"] = B.moe_template(cfg)
-        else:
-            t["ffn"] = B.mlp_template(cfg, gelu=(cfg.family == "audio"))
+    if ffn == MOE:
+        t["ffn"] = B.moe_template(cfg)
+    elif ffn == MLP:
+        t["ffn"] = B.mlp_template(cfg, gelu=(cfg.family == "audio"))
     return t
 
 
@@ -83,10 +99,13 @@ def model_template(cfg: ModelConfig) -> dict:
         t["encoder"] = {"stack": stack_tree(cfg.encoder_layers, enc_unit),
                         "ln": spec([d], ("embed",), "zeros")}
     unit_kinds, reps, tail_kinds = layer_plan(cfg)
-    unit = {f"l{i}": layer_template(cfg, k) for i, k in enumerate(unit_kinds)}
+    unit_ffns, tail_ffns = layer_ffns(cfg)
+    unit = {f"l{i}": layer_template(cfg, k, f)
+            for i, (k, f) in enumerate(zip(unit_kinds, unit_ffns))}
     t["stack"] = stack_tree(reps, unit)
     if tail_kinds:
-        t["tail"] = {f"l{i}": layer_template(cfg, k) for i, k in enumerate(tail_kinds)}
+        t["tail"] = {f"l{i}": layer_template(cfg, k, f)
+                     for i, (k, f) in enumerate(zip(tail_kinds, tail_ffns))}
     if cfg.dtype != "bfloat16":
         import dataclasses as _dc
         from repro.models.params import ParamSpec
@@ -146,8 +165,21 @@ def cache_template(cfg: ModelConfig, batch: int, ctx: int) -> dict:
 # ---------------------------------------------------------------------------
 # Unit application (one pattern repetition)
 # ---------------------------------------------------------------------------
-def _apply_unit_seq(unit_params, x, *, cfg, kinds, positions, impl, enc_out,
-                    enc_positions):
+def _apply_ffn(p, x, *, cfg, ffn, impl, counts, length=None):
+    """The layer's feed-forward block.  Returns (x, counts): a dropless
+    expert layer adds its routing counters (int32 [2]) to ``counts``.
+    ``length``: as in :func:`prefill` (padding is routed to no expert)."""
+    if ffn == MOE:
+        x, c = B.moe_apply(p["ffn"], x, cfg=cfg, impl=impl, length=length)
+        if c is not None:
+            counts = c if counts is None else counts + c
+    elif ffn == MLP:
+        x = B.mlp_apply(p["ffn"], x, cfg=cfg, impl=impl)
+    return x, counts
+
+
+def _apply_unit_seq(unit_params, x, *, cfg, kinds, ffns, positions, impl,
+                    enc_out, enc_positions):
     """Full-sequence unit forward (no cache).  Returns x."""
     for i, kind in enumerate(kinds):
         p = unit_params[f"l{i}"]
@@ -163,22 +195,20 @@ def _apply_unit_seq(unit_params, x, *, cfg, kinds, positions, impl, enc_out,
             x, _ = B.rglru_apply(p["rglru"], x, cfg=cfg, impl=impl)
         elif kind == SSM:
             x, _ = B.ssm_apply(p["ssm"], x, cfg=cfg, impl=impl)
-        if cfg.d_ff:
-            if cfg.is_moe and kind in (ATTN, LOCAL_ATTN):
-                x = B.moe_apply(p["ffn"], x, cfg=cfg, impl=impl)
-            else:
-                x = B.mlp_apply(p["ffn"], x, cfg=cfg, impl=impl)
+        x, _ = _apply_ffn(p, x, cfg=cfg, ffn=ffns[i], impl=impl, counts=None)
     return x
 
 
-def _apply_unit_seq_exact(unit_params, x, *, cfg, kinds, positions, impl,
-                          enc_out, enc_positions, ctx, length=None):
+def _apply_unit_seq_exact(unit_params, x, *, cfg, kinds, ffns, positions,
+                          impl, enc_out, enc_positions, ctx, length=None):
     """Like _apply_unit_seq but computes the attention caches from the exact
     pre-block residual stream (used by prefill).  ``length`` (traced scalar):
     positions >= length are right-padding (bucketed prefill) — attention is
     already exact under a causal mask, so padding only has to be masked out
-    of the KV caches and the recurrent state updates."""
+    of the KV caches and the recurrent state updates.  Returns (x, cache,
+    counts): the unit's dropless routing counters, or None."""
     cache_out: dict = {}
+    counts = None
     for i, kind in enumerate(kinds):
         p = unit_params[f"l{i}"]
         c: dict = {}
@@ -204,18 +234,17 @@ def _apply_unit_seq_exact(unit_params, x, *, cfg, kinds, positions, impl,
         elif kind == SSM:
             x, st = B.ssm_apply(p["ssm"], x, cfg=cfg, impl=impl, length=length)
             c["ssm"] = st
-        if cfg.d_ff:
-            if cfg.is_moe and kind in (ATTN, LOCAL_ATTN):
-                x = B.moe_apply(p["ffn"], x, cfg=cfg, impl=impl)
-            else:
-                x = B.mlp_apply(p["ffn"], x, cfg=cfg, impl=impl)
+        x, counts = _apply_ffn(p, x, cfg=cfg, ffn=ffns[i], impl=impl,
+                               counts=counts, length=length)
         cache_out[f"l{i}"] = c
-    return x, cache_out
+    return x, cache_out, counts
 
 
-def _apply_unit_decode(unit_params, unit_cache, x, *, cfg, kinds, pos, impl):
-    """Single-token unit forward.  Returns (x, new_unit_cache)."""
+def _apply_unit_decode(unit_params, unit_cache, x, *, cfg, kinds, ffns, pos,
+                       impl):
+    """Single-token unit forward.  Returns (x, new_unit_cache, counts)."""
     new_cache: dict = {}
+    counts = None
     for i, kind in enumerate(kinds):
         p = unit_params[f"l{i}"]
         c = unit_cache[f"l{i}"]
@@ -237,13 +266,10 @@ def _apply_unit_decode(unit_params, unit_cache, x, *, cfg, kinds, pos, impl):
                                             impl=impl)
         elif kind == SSM:
             x, nc["ssm"] = B.ssm_decode(p["ssm"], x, c["ssm"], cfg=cfg, impl=impl)
-        if cfg.d_ff:
-            if cfg.is_moe and kind in (ATTN, LOCAL_ATTN):
-                x = B.moe_apply(p["ffn"], x, cfg=cfg, impl=impl)
-            else:
-                x = B.mlp_apply(p["ffn"], x, cfg=cfg, impl=impl)
+        x, counts = _apply_ffn(p, x, cfg=cfg, ffn=ffns[i], impl=impl,
+                               counts=counts)
         new_cache[f"l{i}"] = nc
-    return x, new_cache
+    return x, new_cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -352,18 +378,19 @@ def forward(params, tokens, *, cfg: ModelConfig, impl=None, frontend_emb=None,
             (bsz, enc_out.shape[1]))
 
     unit_kinds, reps, tail_kinds = layer_plan(cfg)
+    unit_ffns, tail_ffns = layer_ffns(cfg)
 
     def unit_body(carry, unit_params):
         out = _apply_unit_seq(unit_params, carry, cfg=cfg, kinds=unit_kinds,
-                              positions=positions, impl=impl, enc_out=enc_out,
-                              enc_positions=enc_pos)
+                              ffns=unit_ffns, positions=positions, impl=impl,
+                              enc_out=enc_out, enc_positions=enc_pos)
         return out, None
 
     x = _scan_stack(unit_body, x, params["stack"], remat)
     if tail_kinds:
         x = _apply_unit_seq(params["tail"], x, cfg=cfg, kinds=tail_kinds,
-                            positions=positions, impl=impl, enc_out=enc_out,
-                            enc_positions=enc_pos)
+                            ffns=tail_ffns, positions=positions, impl=impl,
+                            enc_out=enc_out, enc_positions=enc_pos)
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     if n_front:
         x = x[:, n_front:]
@@ -384,9 +411,19 @@ def loss_fn(params, batch, *, cfg: ModelConfig, impl=None, remat: str = "none"):
     return jnp.mean(logz - gold)
 
 
+def _total(counts) -> jax.Array:
+    """The routing counters summed over the units (zeros where none)."""
+    if counts is None:
+        return jnp.zeros(2, jnp.int32)
+    return counts.reshape(-1, 2).sum(0)
+
+
 def prefill(params, tokens, *, cfg: ModelConfig, impl=None, frontend_emb=None,
-            ctx: Optional[int] = None, length=None):
-    """Prefill: forward + exact KV/state caches.  Returns (logits_last, cache).
+            ctx: Optional[int] = None, length=None, counts: bool = False):
+    """Prefill: forward + exact KV/state caches.  Returns (logits_last, cache),
+    and with ``counts`` a third output: the dropless expert layers' routing
+    counters summed over the layers, int32 [2] (pairs routed to the experts
+    held here, held experts reached; zeros for a model without them).
 
     ctx: cache capacity (>= prompt length); defaults to prompt length.
     length: traced scalar count of REAL prompt tokens when ``tokens`` is
@@ -394,7 +431,8 @@ def prefill(params, tokens, *, cfg: ModelConfig, impl=None, frontend_emb=None,
     logits are then taken at the last real position and the caches are masked
     so they are identical to an unpadded prefill of ``length`` tokens (for
     token-routed MoE layers identity holds per bucket — routing capacity sees
-    the padded length).  None = every token is real (existing behavior)."""
+    the padded length; dropless layers route the real tokens only).  None =
+    every token is real (existing behavior)."""
     x, n_front = _embed_inputs(params, cfg, tokens, frontend_emb)
     bsz, s_tot = x.shape[:2]
     ctx = max(ctx or s_tot, s_tot)   # frontend prefix counts toward capacity
@@ -409,23 +447,27 @@ def prefill(params, tokens, *, cfg: ModelConfig, impl=None, frontend_emb=None,
             jnp.arange(cfg.encoder_seq, dtype=jnp.int32)[None],
             (bsz, enc_out.shape[1]))
     unit_kinds, reps, tail_kinds = layer_plan(cfg)
+    unit_ffns, tail_ffns = layer_ffns(cfg)
 
     def unit_body(carry, unit_params):
-        out, c = _apply_unit_seq_exact(unit_params, carry, cfg=cfg,
-                                       kinds=unit_kinds, positions=positions,
-                                       impl=impl, enc_out=enc_out,
-                                       enc_positions=enc_pos, ctx=ctx,
-                                       length=valid)
-        return out, c
+        out, c, n = _apply_unit_seq_exact(unit_params, carry, cfg=cfg,
+                                          kinds=unit_kinds, ffns=unit_ffns,
+                                          positions=positions, impl=impl,
+                                          enc_out=enc_out,
+                                          enc_positions=enc_pos, ctx=ctx,
+                                          length=valid)
+        return out, (c, n)
 
-    x, stack_cache = jax.lax.scan(unit_body, x, params["stack"])
+    x, (stack_cache, routed) = jax.lax.scan(unit_body, x, params["stack"])
     cache = {"stack": stack_cache}
     if tail_kinds:
-        x, tail_cache = _apply_unit_seq_exact(
-            params["tail"], x, cfg=cfg, kinds=tail_kinds, positions=positions,
-            impl=impl, enc_out=enc_out, enc_positions=enc_pos, ctx=ctx,
-            length=valid)
+        x, tail_cache, n = _apply_unit_seq_exact(
+            params["tail"], x, cfg=cfg, kinds=tail_kinds, ffns=tail_ffns,
+            positions=positions, impl=impl, enc_out=enc_out,
+            enc_positions=enc_pos, ctx=ctx, length=valid)
         cache["tail"] = tail_cache
+        if n is not None:
+            routed = n if routed is None else _total(routed) + n
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     if valid is None:
@@ -433,28 +475,41 @@ def prefill(params, tokens, *, cfg: ModelConfig, impl=None, frontend_emb=None,
     else:
         x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
     logits_last = L.unembed(x_last, table, cfg.tie_embeddings)
+    if counts:
+        return logits_last, cache, _total(routed)
     return logits_last, cache
 
 
-def decode_step(params, cache, tokens, pos, *, cfg: ModelConfig, impl=None):
+def decode_step(params, cache, tokens, pos, *, cfg: ModelConfig, impl=None,
+                counts: bool = False):
     """One decode step.  tokens: [B, 1] int32; pos: [B] int32 absolute
-    position of this token.  Returns (logits [B, 1, V], new_cache)."""
+    position of this token.  Returns (logits [B, 1, V], new_cache), and with
+    ``counts`` the routing counters as :func:`prefill` gives them."""
     x = L.embed(tokens, params["embed"]) * math.sqrt(cfg.d_model)
     x = x.astype(jnp.dtype(cfg.dtype))
     unit_kinds, reps, tail_kinds = layer_plan(cfg)
+    unit_ffns, tail_ffns = layer_ffns(cfg)
 
     def unit_body(carry, scanned):
         unit_params, unit_cache = scanned
-        out, nc = _apply_unit_decode(unit_params, unit_cache, carry, cfg=cfg,
-                                     kinds=unit_kinds, pos=pos, impl=impl)
-        return out, nc
+        out, nc, n = _apply_unit_decode(unit_params, unit_cache, carry,
+                                        cfg=cfg, kinds=unit_kinds,
+                                        ffns=unit_ffns, pos=pos, impl=impl)
+        return out, (nc, n)
 
-    x, new_stack = jax.lax.scan(unit_body, x, (params["stack"], cache["stack"]))
+    x, (new_stack, routed) = jax.lax.scan(
+        unit_body, x, (params["stack"], cache["stack"]))
     new_cache = {"stack": new_stack}
     if tail_kinds:
-        x, nc = _apply_unit_decode(params["tail"], cache["tail"], x, cfg=cfg,
-                                   kinds=tail_kinds, pos=pos, impl=impl)
+        x, nc, n = _apply_unit_decode(params["tail"], cache["tail"], x,
+                                      cfg=cfg, kinds=tail_kinds,
+                                      ffns=tail_ffns, pos=pos, impl=impl)
         new_cache["tail"] = nc
+        if n is not None:
+            routed = n if routed is None else _total(routed) + n
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return L.unembed(x, table, cfg.tie_embeddings), new_cache
+    logits = L.unembed(x, table, cfg.tie_embeddings)
+    if counts:
+        return logits, new_cache, _total(routed)
+    return logits, new_cache

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN with two dispatch strategies.
+"""Mixture-of-Experts FFN: two capacity-bounded dispatch strategies
+(region ``moe_ffn``) and one dropless one (region ``moe_dropless``).
 
 * ``token_onehot`` — GShard-style token-choice top-k with a one-hot dispatch
   tensor [T, E, C].  Exact token-choice semantics; memory O(T*E*C) so it is
@@ -10,6 +11,15 @@
 
 Experts are sharded over the ``model`` mesh axis (expert parallelism); GSPMD
 inserts the token all-to-all when token activations are data-sharded.
+
+``moe_dropless`` has one semantics, token-choice top-k with no capacity: a
+token's experts are the k largest of a float32 softmax over all routed
+experts' logits, chosen from that token alone, and no pick is ever dropped.
+The layer holds the experts ``[first, first + held)`` of them and adds their
+share of the output only (expert parallelism's share, without the exchange).
+Variants: ``ref`` runs every held expert over every token, masked by the
+gate; ``gmm`` sorts the routed pairs by expert and runs the grouped Pallas
+kernels of ``kernels/moe_gmm.py`` over the experts they reach.
 """
 from __future__ import annotations
 
@@ -172,3 +182,95 @@ def aux_load_balance_loss(probs: jax.Array, gate_idx: jax.Array, num_experts: in
     ce = jnp.mean(
         jax.nn.one_hot(gate_idx[:, 0], num_experts, dtype=jnp.float32), axis=0)
     return num_experts * jnp.sum(me * ce)
+
+
+# ---------------------------------------------------------------------------
+# Dropless token-choice top-k over held experts (region "moe_dropless")
+# ---------------------------------------------------------------------------
+def route_topk(x, w_router, *, k: int, renormalize: bool):
+    """Gate weights and expert ids [T, k] of each token: the top ``k`` of a
+    float32 softmax over all routed experts' logits, rescaled to sum 1 only
+    under ``renormalize`` (Mixtral does, Jamba does not)."""
+    logits = jnp.dot(x, w_router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalize:
+        gate = gate / gate.sum(-1, keepdims=True)
+    return gate, idx
+
+
+def _local_ids(idx, first: int, valid):
+    """Picks as indices into the held experts; -1 for a token that is not
+    ``valid`` (bucket padding: routed nowhere)."""
+    local = idx - first
+    return local if valid is None else jnp.where(valid[:, None], local, -1)
+
+
+def _counters(sizes):
+    """The layer's counters int32 [2] from its pairs per held expert:
+    pairs routed to held experts, held experts reached."""
+    return jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+
+
+@register_variant("moe_dropless", "ref")
+def moe_dropless_dense(x, params, *, k: int, first: int = 0,
+                       renormalize: bool = True, valid=None):
+    """Every held expert over every token, weighted by the token's gate for
+    it (0 where it did not pick it).  x: [T, D]; valid: [T] bool or None.
+    Returns ([T, D], counts)."""
+    gate, idx = route_topk(x, params["router"], k=k, renormalize=renormalize)
+    held = params["w_gate"].shape[0]
+    # ids out of [0, held) (held elsewhere, or padding) give zero rows
+    onehot = jax.nn.one_hot(_local_ids(idx, first, valid), held,
+                            dtype=jnp.float32)                # [T, k, held]
+    weight = (onehot * gate[..., None]).sum(1)                # [T, held]
+
+    def expert(acc, inp):
+        wg, wu, wd, w = inp
+        g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+        h = jax.nn.silu(g) * jnp.dot(x, wu, preferred_element_type=jnp.float32)
+        y = jnp.dot(h.astype(x.dtype), wd, preferred_element_type=jnp.float32)
+        return acc + w[:, None] * y, None
+
+    acc, _ = jax.lax.scan(
+        expert, jnp.zeros(x.shape, jnp.float32),
+        (params["w_gate"], params["w_up"], params["w_down"], weight.T))
+    return acc.astype(x.dtype), _counters(onehot.sum((0, 1)))
+
+
+@register_variant("moe_dropless", "gmm")
+def moe_dropless_grouped(x, params, *, k: int, first: int = 0,
+                         renormalize: bool = True, valid=None):
+    """The routed pairs sorted by held expert, each expert's rows padded to
+    whole row tiles, through the grouped kernels; each token sums its held
+    picks.  Rows are sized for the worst case, so no pick is dropped."""
+    from repro.kernels import moe_gmm
+
+    t, d = x.shape
+    gate, idx = route_topk(x, params["router"], k=k, renormalize=renormalize)
+    held = params["w_gate"].shape[0]
+    pairs = t * k
+    tm = moe_gmm.row_tile(pairs, params["router"].shape[-1])
+    m = moe_gmm.padded_rows(pairs, held, tm)
+    local = _local_ids(idx, first, valid).reshape(pairs)
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, 0)
+    onehot = jax.nn.one_hot(local, held, dtype=jnp.int32)     # [pairs, held]
+    sizes = onehot.sum(0)
+    tiles = -(-sizes // tm)                                   # [held]
+    starts = (jnp.cumsum(tiles) - tiles) * tm                 # first row
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0) - onehot,
+                               group[:, None], axis=1)[:, 0]
+    dest = jnp.where(mine, starts[group] + rank, m)           # m: dropped
+    rows = jnp.zeros((m, d), x.dtype).at[dest].set(
+        jnp.repeat(x, k, axis=0), mode="drop")
+    tile_group = jnp.repeat(jnp.arange(held, dtype=jnp.int32), tiles,
+                            total_repeat_length=m // tm)
+    n_tiles = tiles.sum(keepdims=True).astype(jnp.int32)
+    h = moe_gmm.gate_up(rows, params["w_gate"], params["w_up"], tile_group,
+                        n_tiles, tm=tm)
+    y = moe_gmm.down(h, params["w_down"], tile_group, n_tiles, tm=tm)
+    # rows of pairs held elsewhere were never written: select, not scale
+    y_pair = jnp.where(mine[:, None], y[jnp.minimum(dest, m - 1)], 0.0)
+    out = (y_pair * gate.reshape(pairs, 1)).reshape(t, k, d).sum(1)
+    return out.astype(x.dtype), _counters(sizes)

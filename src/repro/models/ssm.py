@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.core.regions import register_variant
 from repro.kernels.ssm_scan import Factored
 from repro.kernels.ssm_scan import ssm_scan as ssm_scan_kernel
+from repro.models.layers import rms_norm
 from repro.parallel import ctx as parallel_ctx
 
 
@@ -32,8 +33,10 @@ from repro.parallel import ctx as parallel_ctx
 # Depthwise causal conv (kernel size K, shift-and-add formulation)
 # ---------------------------------------------------------------------------
 def causal_depthwise_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None,
-                          length: jax.Array | None = None):
-    """x: [B, S, D]; w: [K, D]; state: [B, K-1, D] trailing context or None.
+                          length: jax.Array | None = None,
+                          bias: jax.Array | None = None):
+    """x: [B, S, D]; w: [K, D]; state: [B, K-1, D] trailing context or None;
+    bias: [D] added to every output, or None.
 
     ``length`` (traced scalar): only the first ``length`` positions of x are
     real — the returned state is then the K-1 inputs *ending at* position
@@ -46,6 +49,8 @@ def causal_depthwise_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = 
         state = jnp.zeros((x.shape[0], k - 1, x.shape[2]), x.dtype)
     xp = jnp.concatenate([state, x], axis=1)                  # [B, S+K-1, D]
     y = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(k))
+    if bias is not None:
+        y = y + bias
     if k <= 1:
         new_state = jnp.zeros_like(state)
     elif length is None:
@@ -208,6 +213,21 @@ def ssm_decode_step(a, bx, c, h):
 # ---------------------------------------------------------------------------
 # Full Mamba block
 # ---------------------------------------------------------------------------
+def _dt_b_c(params, xi, cfg):
+    """dt (after dt_proj and softplus), B and C of the mixer input ``xi``.
+    Under ``cfg.ssm_dt_norm`` dt (before dt_proj), B and C are first
+    RMS-normalised with their learned weights (jamba)."""
+    dbc = xi @ params["w_dbc"]                                 # [B, S, dt_rank + 2N]
+    dtr = cfg.resolved_dt_rank
+    dt, bmat, cmat = jnp.split(dbc, [dtr, dtr + cfg.ssm_state], axis=-1)
+    if cfg.ssm_dt_norm:
+        dt = rms_norm(dt, params["dt_ln"], cfg.norm_eps)
+        bmat = rms_norm(bmat, params["b_ln"], cfg.norm_eps)
+        cmat = rms_norm(cmat, params["c_ln"], cfg.norm_eps)
+    dt = jax.nn.softplus(dt @ params["w_dt"] + params["dt_bias"])   # [B, S, Di]
+    return dt, bmat, cmat
+
+
 def mamba_block(params, x, *, cfg, impl=None, state=None, length=None):
     """x: [B, S, D_model].  state: None (train) or dict(conv, h) for decode-
     style stateful prefill.  ``length`` (traced scalar): positions >= length
@@ -222,14 +242,12 @@ def mamba_block(params, x, *, cfg, impl=None, state=None, length=None):
     xi, z = jnp.split(xz, 2, axis=-1)
     conv_state = None if state is None else state["conv"]
     xi, new_conv = causal_depthwise_conv(xi, params["conv_w"], conv_state,
-                                         length=length)
+                                         length=length,
+                                         bias=params.get("conv_b"))
     xi = jax.nn.silu(xi)
 
     # input-dependent dt, B, C
-    dbc = xi @ params["w_dbc"]                                 # [B, S, dt_rank + 2N]
-    dtr = cfg.resolved_dt_rank
-    dt, bmat, cmat = jnp.split(dbc, [dtr, dtr + n], axis=-1)
-    dt = jax.nn.softplus(dt @ params["w_dt"] + params["dt_bias"])   # [B, S, Di]
+    dt, bmat, cmat = _dt_b_c(params, xi, cfg)
     a_log = -jnp.exp(params["a_log"].astype(jnp.float32))      # [Di, N]
 
     if length is not None:
@@ -253,17 +271,13 @@ def mamba_block(params, x, *, cfg, impl=None, state=None, length=None):
 
 def mamba_decode_step(params, x, state, *, cfg, impl=None):
     """x: [B, 1, D_model]; state: dict(conv [B, K-1, Di], h [B, Di, N])."""
-    b = x.shape[0]
-    di, n = cfg.d_inner, cfg.ssm_state
     xz = x @ params["w_in"]
     xi, z = jnp.split(xz, 2, axis=-1)                          # [B, 1, Di]
-    xi, new_conv = causal_depthwise_conv(xi, params["conv_w"], state["conv"])
+    xi, new_conv = causal_depthwise_conv(xi, params["conv_w"], state["conv"],
+                                         bias=params.get("conv_b"))
     xi = jax.nn.silu(xi)
 
-    dbc = xi @ params["w_dbc"]
-    dtr = cfg.resolved_dt_rank
-    dt, bmat, cmat = jnp.split(dbc, [dtr, dtr + n], axis=-1)
-    dt = jax.nn.softplus(dt @ params["w_dt"] + params["dt_bias"])
+    dt, bmat, cmat = _dt_b_c(params, xi, cfg)
     a_log = -jnp.exp(params["a_log"].astype(jnp.float32))
 
     a = jnp.exp(dt[:, 0, :, None].astype(jnp.float32) * a_log)     # [B, Di, N]

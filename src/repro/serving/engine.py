@@ -22,6 +22,13 @@ distinct prompt length — the serving analogue of the per-pattern recompile
 the offload-proposal paper (arXiv 2004.08548) warns naive placement pays.
 ``prefill_traces`` counts actual compilations for observability.
 
+Decode ahead: the greedy tokens and the finite check are computed on the
+device behind each step, so one small copy reaches the host per step.
+When every slot decodes, none finishes and all are greedy, the next tick's
+step is known before this one's tokens reach the host: it is dispatched
+on the device's tokens at once, and the host's bookkeeping overlaps it.
+The next tick uses it only if its inputs are the ones the tick would pass.
+
 Admission control: ``submit()`` rejects requests whose prompt + frontend
 prefix + max_new_tokens cannot fit the cache (the overflow used to silently
 corrupt cache rows via the decode-step ``min(pos, ctx-1)`` slot clamp).
@@ -150,6 +157,43 @@ def cache_insert(full_cache, one_cache, slot: int):
         jax.tree_util.tree_structure(full_cache), out)
 
 
+def _greedy_check(logits):
+    """Each row's greedy token at its last position ([rows, 1], the shape
+    a decode step takes its tokens in), and whether every logit is finite:
+    the guard copies these to the host in place of the logits themselves."""
+    return (jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32),
+            jnp.all(jnp.isfinite(logits)))
+
+
+@dataclass
+class _Ahead:
+    """A decode step dispatched one tick early, while the step before it
+    still ran: what it was given and its outputs, still on the device.
+    ``tokens`` is filled once the guard has read the earlier step's greedy
+    tokens, which the early step took on the device."""
+    gen: "PlanGeneration"
+    params: object
+    cache: object
+    pos: np.ndarray
+    out: tuple
+    checked: tuple
+    tokens: Optional[np.ndarray] = None
+
+    def serves(self, gen, args) -> bool:
+        """Whether this step is the one ``gen.decode(*args)`` would run."""
+        params, cache, toks, pos = args
+        return (gen is self.gen and params is self.params
+                and cache is self.cache and self.tokens is not None
+                and np.array_equal(toks, self.tokens)
+                and np.array_equal(pos, self.pos))
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of every array in a pytree (a slot's cache rows: KV and
+    recurrent state alike)."""
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+
 def _block(tree) -> None:
     """Wait for every device buffer in a pytree (warm-up barrier)."""
     for leaf in jax.tree_util.tree_leaves(tree):
@@ -223,7 +267,7 @@ class ServeEngine:
         self.ctx = ctx
         self.seed = seed
         self._sample = jax.jit(make_sampler(seed))
-        self._argmax = jax.jit(lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        self._check = jax.jit(_greedy_check)
         self.prefill_traces = 0
         self.buckets_seen: set[int] = set()
         # (bucket, frontend signature) shapes actually prefilled — what
@@ -231,6 +275,12 @@ class ServeEngine:
         # on the tick path
         self._prefill_shapes: set[tuple] = set()
         self.cache = F.init_cache(cfg, slots, ctx)
+        self._ahead: Optional[_Ahead] = None   # next tick's decode, dispatched
+        self.decoded_ahead = 0           # decode steps served from _ahead
+        self._room_ahead: Optional[bool] = None  # read once, when first asked
+        # dropless expert layers: the steps also return their routing
+        # counters, copied with the logits and kept on the step's span
+        self._counted = cfg.moe_dropless
         self.queue: deque[Request] = deque()
         self.active: list[Optional[Request]] = [None] * slots
         self.pos = np.zeros(slots, np.int32)          # next absolute position
@@ -277,8 +327,8 @@ class ServeEngine:
         with self._plan_lock:
             cached = self._trace_memo.get(key)
         if cached is None:
-            raw_prefill = F.make_bucketed_prefill_step(self.cfg, impl=merged,
-                                                       ctx=self.ctx)
+            raw_prefill = F.make_bucketed_prefill_step(
+                self.cfg, impl=merged, ctx=self.ctx, counts=self._counted)
 
             def counted_prefill(params, batch, length):
                 # body runs at trace time only: counts one compilation per
@@ -288,7 +338,8 @@ class ServeEngine:
                 return raw_prefill(params, batch, length)
 
             built = (jax.jit(counted_prefill),
-                     jax.jit(F.make_serve_step(self.cfg, impl=merged)))
+                     jax.jit(F.make_serve_step(self.cfg, impl=merged,
+                                               counts=self._counted)))
             with self._plan_lock:
                 # two threads may have built concurrently: first one wins so
                 # both use the same jitted objects (shared dispatch cache)
@@ -378,28 +429,87 @@ class ServeEngine:
         runtime guard.  A kernel exception or non-finite logits triggers a
         rollback to the last healthy generation and a retry of the same
         call, so the in-flight request never observes the fault.  Raises
-        only when no rollback target remains (the all-ref plan itself is
-        faulting).
+        only when no rollback target remains (the all-reference plan itself
+        is faulting).
 
-        The wait for the device (``engine.wait``) is timed apart from the
-        logits' copy to the host and their finite check (``engine.guard``)."""
+        Returns ``(logits, cache, counters, greedy)``: the step's logits and
+        cache on the device, a counted step's routing counters (its third
+        output) or None, and each row's greedy token.  The finite check and
+        the greedy tokens are computed on the device behind the step, so
+        one small copy reaches the host per call.  The wait for the device
+        (``engine.wait``) is timed apart from that copy and the check
+        (``engine.guard``)."""
         while True:
             gen = self._gen
+            ahead, self._ahead = self._ahead, None
             try:
-                out = getattr(gen, op)(*args)
+                if op == "decode" and ahead is not None \
+                        and ahead.serves(gen, args):
+                    out, checked = ahead.out, ahead.checked
+                    self.decoded_ahead += 1
+                else:
+                    out = getattr(gen, op)(*args)
+                    checked = self._check(out[0])
+                if op == "decode" and self._next_decode_known():
+                    self._ahead = self._decode_ahead(gen, args, out, checked)
                 with obs.span("engine.wait"):
-                    jax.block_until_ready(out[0])
+                    jax.block_until_ready(checked)
                 with obs.span("engine.guard"):
-                    finite = np.all(np.isfinite(np.asarray(out[0])))
+                    greedy, finite, *counters = jax.device_get(
+                        (*checked, *out[2:3]))
                 if not finite:
                     raise PlanFault(
                         f"{op} produced non-finite logits under plan "
                         f"{gen.impl.describe()!r}")
-                return out
+                if self._ahead is not None:
+                    self._ahead.tokens = greedy
+                return (*out[:2], counters[0] if counters else None,
+                        greedy[:, 0])
             except Exception as err:  # noqa: BLE001 — every tick-path plan
                 # failure routes through rollback, whatever its type
+                self._ahead = None
                 if not self._rollback(gen, op, err):
                     raise
+
+    def _next_decode_known(self) -> bool:
+        """Whether the next tick's decode step is known before this one's
+        tokens reach the host: every slot decodes and none finishes now (so
+        the next tick admits nothing and feeds every slot the token this
+        step picks), every slot decodes greedily, and no plan waits to be
+        installed."""
+        return (all(r is not None
+                    and len(r.generated) + 1 < r.max_new_tokens
+                    for r in self.active)
+                and not np.any(self._temps > 0.0)
+                and self._pending_plan is None
+                and self._has_room_ahead())
+
+    def _has_room_ahead(self) -> bool:
+        """Whether the device holds a second decode step in flight: one
+        more cache and that step's temporaries, reckoned as two caches,
+        beside the most it has held so far.  Read once, at the first tick
+        that could run ahead (after the first prefills); a device that
+        reports no limit has room."""
+        if self._room_ahead is None:
+            leaves = jax.tree_util.tree_leaves(self.cache)
+            need = 2 * _tree_bytes(self.cache)
+            room = []
+            for dev in {d for leaf in leaves for d in leaf.devices()}:
+                stats = dev.memory_stats() or {}
+                if "bytes_limit" in stats:
+                    room.append(stats["bytes_limit"]
+                                - stats.get("peak_bytes_in_use", 0))
+            self._room_ahead = not room or min(room) >= need
+        return self._room_ahead
+
+    def _decode_ahead(self, gen, args, out, checked) -> _Ahead:
+        """Dispatch the next tick's decode step behind this one, on this
+        step's cache and greedy tokens, so the device need not wait for the
+        host between the two."""
+        params, _, _, pos = args
+        pos = np.asarray(pos) + 1
+        nxt = gen.decode(params, out[1], checked[0], pos)
+        return _Ahead(gen, params, out[1], pos, nxt, self._check(nxt[0]))
 
     def _rollback(self, failed: PlanGeneration, op: str,
                   err: Exception) -> bool:
@@ -455,8 +565,8 @@ class ServeEngine:
         toks = jnp.zeros((self.slots, 1), jnp.int32)
         pos = jnp.zeros((self.slots,), jnp.int32)
         try:
-            logits, _ = prepared.decode(self.params, self._warm_cache,
-                                        toks, pos)
+            logits = prepared.decode(self.params, self._warm_cache,
+                                     toks, pos)[0]
             cand = np.asarray(logits)
         except Exception as err:  # noqa: BLE001 — any failure mode rejects
             return False, f"canary decode raised: {err}"
@@ -464,8 +574,8 @@ class ServeEngine:
             return False, "canary decode produced non-finite logits"
         if ref is not None and prepared.key != ref.key:
             try:
-                ref_logits, _ = ref.decode(self.params, self._warm_cache,
-                                           toks, pos)
+                ref_logits = ref.decode(self.params, self._warm_cache,
+                                        toks, pos)[0]
                 ref_arr = np.asarray(ref_logits)
             except Exception as err:  # noqa: BLE001 — a faulting reference
                 # cannot veto the candidate; the finite check already passed
@@ -554,15 +664,29 @@ class ServeEngine:
         return bool(self.queue) or any(r is not None for r in self.active)
 
     # ------------------------------------------------------------------
-    def _sample_tokens(self, logits, rids, steps, temps, top_ks) -> np.ndarray:
+    def _sample_tokens(self, logits, rids, steps, temps, top_ks,
+                       greedy=None) -> np.ndarray:
+        """The next token of each row of ``logits`` ([rows, 1, vocab]):
+        ``greedy`` (the guard's argmax, already on the host) where no row
+        samples, else drawn on the device."""
         if not np.any(np.asarray(temps) > 0.0):
             # all-greedy tick (the default workload): skip the per-slot
             # sort + categorical work entirely
-            return np.asarray(self._argmax(logits), np.int32)
+            if greedy is None:
+                greedy = self._check(logits)[0][:, 0]
+            return np.asarray(greedy, np.int32)
         return np.asarray(self._sample(
-            logits, jnp.asarray(rids, jnp.int32), jnp.asarray(steps, jnp.int32),
-            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32)),
-            np.int32)
+            logits[:, -1], jnp.asarray(rids, jnp.int32),
+            jnp.asarray(steps, jnp.int32), jnp.asarray(temps, jnp.float32),
+            jnp.asarray(top_ks, jnp.int32)), np.int32)
+
+    @staticmethod
+    def _note_routed(sp, out) -> None:
+        """Keep a counted step's routing counters on its span: the (token,
+        expert) pairs routed to the experts held here, and the held experts
+        they reached, summed over the expert layers."""
+        if len(out) > 2 and out[2] is not None:
+            sp.set(moe_pairs=int(out[2][0]), moe_experts_hit=int(out[2][1]))
 
     def _retire(self, slot: int) -> None:
         req = self.active[slot]
@@ -603,15 +727,17 @@ class ServeEngine:
                 fe_sig = (key, tuple(fe.shape[1:]), str(fe.dtype))
             self._prefill_shapes.add((bucket, fe_sig))
             with obs.span("engine.prefill", rid=req.rid, bucket=bucket,
-                          prompt_len=n):
-                logits, one_cache = self._plan_call(
+                          prompt_len=n) as sp:
+                out = self._plan_call(
                     "prefill", self.params, batch, jnp.asarray(n, jnp.int32))
-            with obs.span("engine.insert"):
+                logits, one_cache = out[:2]
+                self._note_routed(sp, out)
+            with obs.span("engine.insert", bytes=_tree_bytes(one_cache)):
                 self.cache = cache_insert(self.cache, one_cache, slot)
             with obs.span("engine.sample"):
                 first = int(self._sample_tokens(
-                    logits[:, -1], [req.rid], [0],
-                    [req.sampling.temperature], [req.sampling.top_k])[0])
+                    logits, [req.rid], [0], [req.sampling.temperature],
+                    [req.sampling.top_k], *out[3:4])[0])
             req.generated.append(first)
             req.admit_s = time.perf_counter()
             self.active[slot] = req
@@ -630,20 +756,22 @@ class ServeEngine:
         decoding = sum(r is not None for r in self.active)
         if not decoding:
             return 0
-        with obs.span("engine.decode", slots=decoding):
-            toks = jnp.asarray(self.last_tok[:, None])
-            pos = jnp.asarray(self.pos)
+        with obs.span("engine.decode", slots=decoding) as sp:
+            # fresh host copies: the step may read them after this tick
+            # has moved on, and a step dispatched ahead is matched on them
+            toks = self.last_tok[:, None].copy()
+            pos = self.pos.copy()
             # commit the cache only AFTER the guard: a faulting plan's
             # outputs (logits AND cache) are discarded whole, so a rollback
             # retries the step from the exact pre-tick state
-            logits, new_cache = self._plan_call("decode", self.params,
-                                                self.cache, toks, pos)
-            self.cache = new_cache
+            out = self._plan_call("decode", self.params, self.cache, toks, pos)
+            logits, self.cache = out[:2]
+            self._note_routed(sp, out)
             steps = np.asarray([len(r.generated) if r is not None else 0
                                 for r in self.active], np.int32)
             with obs.span("engine.sample"):
-                nxt = self._sample_tokens(logits[:, -1], self._rids, steps,
-                                          self._temps, self._top_ks)
+                nxt = self._sample_tokens(logits, self._rids, steps,
+                                          self._temps, self._top_ks, *out[3:4])
         for slot, req in enumerate(self.active):
             if req is None:
                 continue

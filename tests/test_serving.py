@@ -243,6 +243,49 @@ def test_sampling_params_validate():
 # ---------------------------------------------------------------------------
 # Lifecycle stats
 # ---------------------------------------------------------------------------
+def _full_slots_run(cfg, params, ahead: bool, sampling=None):
+    """Six requests over three slots, every slot busy for several ticks;
+    with ``ahead`` False the engine never dispatches a decode early."""
+    eng = ServeEngine(cfg, params, slots=3, ctx=32)
+    if not ahead:
+        eng._next_decode_known = lambda: False
+    for i, p in enumerate(_prompts(cfg, 6)):
+        eng.submit(p, max_new_tokens=6 + 2 * i,
+                   **({"sampling": sampling} if sampling else {}))
+    return eng, {r.rid: r.generated for r in eng.run_to_completion()}
+
+
+def test_decode_dispatched_ahead_gives_the_same_tokens(setup):
+    """With every slot decoding and none finishing, the next tick's step is
+    dispatched before this one's tokens reach the host; the tokens are
+    those of an engine that always waits."""
+    cfg, params = setup
+    eng, early = _full_slots_run(cfg, params, ahead=True)
+    _, waited = _full_slots_run(cfg, params, ahead=False)
+    assert eng.decoded_ahead > 0
+    assert early == waited
+
+
+def test_no_decode_dispatched_ahead_when_sampling_or_a_plan_waits(setup):
+    """Nor where the device lacks room for a second step's cache."""
+    cfg, params = setup
+    eng, _ = _full_slots_run(cfg, params, ahead=True,
+                             sampling=SamplingParams(temperature=0.8))
+    assert eng.decoded_ahead == 0
+    eng = ServeEngine(cfg, params, slots=3, ctx=32)
+    eng._room_ahead = False
+    for p in _prompts(cfg, 3):
+        eng.submit(p, max_new_tokens=8)
+    eng.run_to_completion()
+    assert eng.decoded_ahead == 0
+    eng = ServeEngine(cfg, params, slots=2, ctx=32)
+    for p in _prompts(cfg, 2):
+        eng.submit(p, max_new_tokens=8)
+    eng.step()
+    eng.offer_plan(eng.prepare_plan(eng.plan_impl, warm=False))
+    assert not eng._next_decode_known()
+
+
 def test_request_stats_populated(setup):
     cfg, params = setup
     eng = ServeEngine(cfg, params, slots=1, ctx=32)

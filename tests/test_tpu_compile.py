@@ -10,6 +10,8 @@ worker running this file loads the TPU library.
 """
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,10 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mriq import mriq_compute_q
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.ssm_scan import Factored, ssm_scan
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:          # the benchmark's configuration files
+    sys.path.insert(0, str(ROOT))
 
 
 @pytest.fixture(scope="module")
@@ -150,3 +156,69 @@ def test_flash_attention_compiles_at_head_dim_128(one_chip):
              [((1, cfg.num_heads, s, hd), jnp.bfloat16),
               ((1, cfg.num_kv_heads, s, hd), jnp.bfloat16),
               ((1, cfg.num_kv_heads, s, hd), jnp.bfloat16)], one_chip)
+
+
+@pytest.mark.parametrize("rows,tm", [(8192, 512), (160, 16)])
+def test_grouped_expert_kernels_compile_at_jamba_widths(one_chip, rows, tm):
+    """The dropless expert layer's two kernels at jamba2-mini's published
+    widths (4,096 -> 14,336 -> 4,096), 8 held experts: a 4,096-token
+    prefill's 8,192 pairs and a 16-slot decode step's rows.  The kernel is
+    the custom call named ``moe_gmm`` that the benchmark reads."""
+    from repro.kernels.moe_gmm import down, gate_up
+    cfg = get_config("jamba2-mini")
+    d, f, g = cfg.d_model, cfg.moe_d_ff, 8
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    meta = [((rows // tm,), i32), ((1,), i32)]
+    up = _compile(lambda x, wg, wu, t, n: gate_up(x, wg, wu, t, n, tm=tm,
+                                                  interpret=False),
+                  [((rows, d), bf16), ((g, d, f), bf16), ((g, d, f), bf16),
+                   *meta], one_chip)
+    dn = _compile(lambda h, wd, t, n: down(h, wd, t, n, tm=tm,
+                                           interpret=False),
+                  [((rows, f), bf16), ((g, f, d), bf16), *meta], one_chip)
+    for compiled in (up, dn):
+        assert re.search(r"%moe_gmm(\.\d+)? = .*custom-call",
+                         compiled.as_text())
+
+
+def test_jamba_cut_prefill_at_4096_compiles_within_the_chip(one_chip,
+                                                            monkeypatch):
+    """The benchmark's jamba2-mini cut (one period, 8 of 16 experts, the
+    vocabulary slice) through its served plan's bucketed prefill at the
+    largest bucket: compiled with both Mosaic kernels, and its weights,
+    the 16-slot cache and the step's temporaries fit the chip's 16 GB."""
+    import repro.kernels.moe_gmm as G
+    import repro.kernels.ssm_scan as K
+    from bench.harness import load_module
+    from repro.core.regions import Impl
+    from repro.models import factory as F
+
+    for mod in (G, K):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda interpret: False)
+    for fn in (G.gate_up, G.down, K.ssm_scan):
+        fn.clear_cache()
+    counts = load_module(ROOT / "bench/configs/jamba2-mini.py")
+    conf = counts.CONF
+    cfg = counts.model_config(conf)
+    ctx = conf["serving"]["ctx"]
+
+    def placed(tree):
+        return jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=one_chip), tree)
+    step = F.make_bucketed_prefill_step(cfg, Impl(conf["serving"]["plan"]),
+                                        ctx=ctx, counts=True)
+    try:
+        compiled = jax.jit(step).lower(
+            placed(F.abstract_params(cfg)),
+            {"tokens": jax.ShapeDtypeStruct((1, 4096), jnp.int32,
+                                            sharding=one_chip)},
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    finally:
+        for fn in (G.gate_up, G.down, K.ssm_scan):
+            fn.clear_cache()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "ssm_scan" in text
+    need = (counts.weight_bytes(conf)
+            + conf["serving"]["slots"] * counts.cache_bytes_per_slot(conf, ctx)
+            + compiled.memory_analysis().temp_size_in_bytes)
+    assert need < 16e9
