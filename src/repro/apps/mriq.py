@@ -3,8 +3,8 @@
 The Parboil C source has 16 loop statements (paper §5.1.2).  Pipeline:
 ComputePhiMag loop -> ComputeQ (outer voxel loop x inner k-space loop, the
 hot nest) -> result checksum loop.  ``ref`` variants mirror the C loop
-structure (sequential fori over k-space samples); ``offload`` is the blocked
-matmul+VPU formulation the Pallas kernel implements.
+structure (sequential fori over k-space samples); ``offload`` is XLA's
+blocked outer-product form; ``pallas`` the VPU kernel of kernels/mriq.py.
 """
 from __future__ import annotations
 
@@ -57,7 +57,7 @@ def _q_ref(x, y, z, kx, ky, kz, pm):
 
 @register_variant("compute_q", "offload")
 def _q_offload(x, y, z, kx, ky, kz, pm):
-    """Blocked outer-product formulation (= the Pallas kernel's math)."""
+    """Blocked outer-product formulation, left to XLA."""
     return mriq_ref(x, y, z, kx, ky, kz, pm, chunk=2048)
 
 

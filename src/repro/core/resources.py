@@ -33,6 +33,7 @@ import numpy as np
 
 from repro import obs
 from repro.kernels.fir import fir_vmem_bytes
+from repro.kernels.mriq import default_blocks as mriq_default_blocks
 
 VMEM_BUDGET = 16 * 1024 * 1024      # 16 MiB per TPU core
 
@@ -134,9 +135,11 @@ def _fir_vmem(x, h, *_):
 
 
 @register_vmem_estimator("compute_q", "pallas")
-def _mriq_vmem(x, *_):
-    bx, bk = 256, 512
-    return 4.0 * (bx * 4 + 4 * bk + 3 * bx * bk)
+def _mriq_vmem(x, y, z, kx, *_):
+    # x, y, z and the two sums in [rows, 128] blocks, double buffered; the
+    # k-space chunk lies in SMEM
+    block_x, _ = mriq_default_blocks(x.shape[0], kx.shape[0])
+    return 4.0 * 2 * 5 * block_x
 
 
 @register_vmem_estimator("attn_core", "pallas")

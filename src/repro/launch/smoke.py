@@ -149,9 +149,9 @@ def kernel_cases(key: jax.Array, *, fir: TdFirConfig, mriq: MriQConfig,
                    "float32 complex MACs on the VPU on both sides; only the "
                    "grouping of each MAC's four real products differs"),
         KernelCase("compute_q", mriq_args, {}, 1e-4,
-                   "float32 contractions (HIGHEST) on both sides; one "
-                   "bfloat16 MXU pass in any of them reads >= 2.3e-03 on a "
-                   "v5e"),
+                   "float32 on the VPU on both sides; the kernel reduces "
+                   "the phase exactly in cycles where ref rounds 2*pi*t in "
+                   "radians, and sums each chunk's samples in turn"),
         KernelCase("ssm_scan", ssm_args, {}, 1e-4,
                    "float32 recurrence on both sides; the reference's "
                    "associative scan regroups the products"),

@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ref as R
 from repro.kernels.fir import fir_filter_bank
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.mriq import mriq_compute_q
+from repro.kernels.mriq import mriq_compute_q, sincos
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssm_scan import Factored, ssm_scan
@@ -62,9 +62,9 @@ def test_fir_ref_matches_c_loop_structure():
 # MRI-Q
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("num_x,num_k,bx,bk", [
-    (128, 128, 128, 128),
-    (300, 200, 128, 128),     # non-multiples exercise padding
-    (1024, 512, 256, 512),
+    (128, 128, 1024, 128),
+    (300, 200, 1024, 128),    # non-multiples exercise padding, two chunks
+    (1024, 512, 1024, 512),
 ])
 def test_mriq_kernel_matches_ref(num_x, num_k, bx, bk):
     ks = jax.random.split(KEY, 7)
@@ -78,6 +78,48 @@ def test_mriq_kernel_matches_ref(num_x, num_k, bx, bk):
                                rtol=3e-3, atol=3e-3)
     np.testing.assert_allclose(np.asarray(qi), np.asarray(qi_ref),
                                rtol=3e-3, atol=3e-3)
+
+
+@pytest.mark.parametrize("bx,bk", [(None, None), (1024, 256)])
+def test_mriq_kernel_at_cell_ranges(bx, bk):
+    # as the benchmark's Parboil-large cell draws them: phases up to about
+    # 48 cycles, where the kernel's quadrant choice is exercised in full
+    ks = jax.random.split(KEY, 7)
+    num_x, num_k = 2000, 600               # multiples of neither tile
+    x, y, z = (jax.random.uniform(ks[i], (num_x,), jnp.float32, -0.5, 0.5)
+               for i in range(3))
+    kx, ky, kz = (jax.random.uniform(ks[3 + i], (num_k,), jnp.float32,
+                                     -32.0, 32.0) for i in range(3))
+    pm = jax.random.uniform(ks[6], (num_k,))
+    qr, qi = mriq_compute_q(x, y, z, kx, ky, kz, pm, block_x=bx, block_k=bk,
+                            interpret=True)
+    x64, y64, z64, kx64, ky64, kz64, pm64 = (
+        np.asarray(a, np.float64) for a in (x, y, z, kx, ky, kz, pm))
+    ph = 2 * np.pi * (np.outer(x64, kx64) + np.outer(y64, ky64)
+                      + np.outer(z64, kz64))
+    for q, want in ((qr, np.cos(ph) @ pm64), (qi, np.sin(ph) @ pm64)):
+        err = np.max(np.abs(np.asarray(q, np.float64) - want))
+        assert err / np.max(np.abs(want)) <= 2e-5
+
+
+def test_mriq_sincos_matches_float64():
+    # random phases up to 64 cycles, and the points within a few ulps of
+    # every quarter cycle and every eighth (half a quarter), where the
+    # quadrant flips or the rounding of 4t ties
+    rng = np.random.default_rng(0)
+    edges = np.concatenate([np.arange(-256, 257) / 4.0,
+                            np.arange(-512, 512) / 8.0]).astype(np.float32)
+    near, up, down = [edges], edges, edges
+    for _ in range(3):
+        up = np.nextafter(up, np.float32(np.inf))
+        down = np.nextafter(down, np.float32(-np.inf))
+        near += [up, down]
+    t = np.concatenate([rng.uniform(-64, 64, 100_000).astype(np.float32),
+                        *near]).astype(np.float32)
+    sin, cos = jax.jit(sincos)(jnp.asarray(t))
+    t64 = 2 * np.pi * t.astype(np.float64)
+    assert np.max(np.abs(np.asarray(sin, np.float64) - np.sin(t64))) <= 2e-7
+    assert np.max(np.abs(np.asarray(cos, np.float64) - np.cos(t64))) <= 2e-7
 
 
 def test_mriq_ref_matches_c_loop_structure():

@@ -8,6 +8,7 @@ unsupported primitives).  Every compiled program must hold a Mosaic kernel
 import, so that every test worker collects the same tests and only the
 worker running this file loads the TPU library.
 """
+import functools
 import os
 import re
 import sys
@@ -75,8 +76,60 @@ def test_fir_compiles_with_lane_blocked_banks(one_chip):
 def test_mriq_compiles_at_parboil_large(one_chip):
     fx = ((MRIQ_FULL.num_x,), jnp.float32)
     fk = ((MRIQ_FULL.num_k,), jnp.float32)
-    _compile(lambda *a: mriq_compute_q(*a, interpret=False),
-             [fx, fx, fx, fk, fk, fk, fk], one_chip)
+    fn = lambda *a: mriq_compute_q(*a, interpret=False)  # noqa: E731
+    _compile(fn, [fx, fx, fx, fk, fk, fk, fk], one_chip)
+    # the VPU alone does the arithmetic, in float32: no contraction (an MXU
+    # pass), no sin or cos of its own range reduction, no bfloat16
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(*s)
+                                 for s in [fx, fx, fx, fk, fk, fk, fk]])
+    prims, dtypes = _walk(jaxpr.jaxpr)
+    assert "pallas_call" in prims
+    assert not prims & {"dot_general", "sin", "cos"}
+    assert jnp.bfloat16 not in dtypes
+
+
+def _walk(jaxpr):
+    """Primitive names and value dtypes of ``jaxpr`` and every jaxpr
+    nested in its equations' parameters (a Pallas kernel's among them)."""
+    prims, dtypes = set(), set()
+
+    def walk(j):
+        for eqn in j.eqns:
+            prims.add(eqn.primitive.name)
+            dtypes.update(v.aval.dtype for v in eqn.outvars
+                          if hasattr(v.aval, "dtype"))
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr)
+    return prims, dtypes
+
+
+def test_mriq_round_one_pattern_keeps_q_in_vmem(one_chip, monkeypatch):
+    """The staged search's round 1 times ``compute_q: pallas`` beside the
+    loop-faithful checksum, a scalar loop over Q.  Where Q lies in HBM that
+    loop reads each element from HBM, the pattern measures slower than the
+    all-ref baseline and the kernel is never selected: the loop's Q operands
+    must be in VMEM (memory space S(1))."""
+    from repro.apps import mriq
+    from repro.core.regions import Impl
+
+    monkeypatch.setattr(mriq, "mriq_compute_q", functools.partial(
+        mriq_compute_q, interpret=False))
+    prog = mriq.make_program(MRIQ_FULL, MRIQ_FULL)
+    fx = ((MRIQ_FULL.num_x,), jnp.float32)
+    fk = ((MRIQ_FULL.num_k,), jnp.float32)
+    text = _compile(prog.build(Impl({"compute_q": "pallas"})),
+                    [fx, fx, fx, fk, fk, fk, fk, fk], one_chip).as_text()
+    loops = [line.split(" while(")[0] for line in text.splitlines()
+             if " while(" in line and "mriq_check:ref/while" in line]
+    assert loops
+    q = re.compile(rf"f32\[{MRIQ_FULL.num_x}\]{{[^}}]*}}")
+    layouts = [m for loop in loops for m in q.findall(loop)]
+    assert len(layouts) >= 2 and all("S(1)" in s for s in layouts), layouts
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
